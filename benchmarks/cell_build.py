@@ -91,6 +91,7 @@ def run(report: Report) -> None:
     env = dict(os.environ)
     env["PYTHONPATH"] = ("src" + os.pathsep + env["PYTHONPATH"]
                          if env.get("PYTHONPATH") else "src")
+    env["JAX_PLATFORMS"] = "cpu"     # the case measures host RSS, not the chip
     rows = []
     with tempfile.TemporaryDirectory() as tmp:
         for n in sizes:

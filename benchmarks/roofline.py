@@ -26,7 +26,8 @@ regression gate holds the solver to (``BENCH_solver.json``, read by
                      ``core/cv.solve_columns_at``).  Both runs must end
                      with KKT residual <= tol.
   * ``roofline``   — analytic flops/byte of one fused CD epoch against
-                     the TPU v5e ridge (197 TFLOP/s bf16 / 819 GB/s HBM):
+                     the ridge of the device it runs on (``DEVICE_PEAKS``;
+                     v5e: 197 TFLOP/s bf16 / 819 GB/s HBM):
                      per epoch the Gram (4 n^2 bytes/slot, f32) streams
                      once while the resident state does 2 n^2 P flops of
                      rank-1 maintenance, so intensity ~= P/2 flops/byte —
@@ -53,8 +54,19 @@ from repro.core.solvers import base as qp
 from repro.kernels.cd_solver import ops as cd_ops
 from repro.kernels.cd_solver import ref as cd_ref
 
-PEAK_FLOPS = 197e12        # TPU v5e bf16 per chip
-HBM_BW = 819e9             # bytes/s per chip
+# Published per-chip peaks keyed by jax's ``device_kind`` (Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM).  A device not
+# listed here has no roofline: ``device_peaks`` raises instead of guessing.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def device_peaks(device_kind: str) -> dict:
+    if device_kind not in DEVICE_PEAKS:
+        raise KeyError(f"no published peaks for device_kind {device_kind!r} "
+                       f"(known: {sorted(DEVICE_PEAKS)})")
+    return DEVICE_PEAKS[device_kind]
 
 OUT_PATH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                         "BENCH_solver.json")
@@ -250,8 +262,8 @@ def bench_warm_start(report: Report, n, p, repeats) -> dict:
             "max_rel_diff": diff}
 
 
-def roofline(s, n, p, epochs, t_wave_s) -> dict:
-    """Analytic flops/byte of the fused CD epoch vs the TPU v5e ridge.
+def roofline(s, n, p, epochs, t_wave_s, device_kind: str) -> dict:
+    """Analytic flops/byte of the fused CD epoch vs the device's ridge.
 
     Per slot-epoch: every coordinate does a rank-1 gradient update
     (n multiplies + n adds per grid column) plus the 1-D step — the
@@ -262,12 +274,13 @@ def roofline(s, n, p, epochs, t_wave_s) -> dict:
     flops = 2.0 * n * n * p * s * epochs
     bytes_moved = (4.0 * n * n + 4 * 4.0 * n * p) * s * epochs
     intensity = flops / bytes_moved
-    ridge = PEAK_FLOPS / HBM_BW
-    t_mem = bytes_moved / HBM_BW
-    t_comp = flops / PEAK_FLOPS
+    peaks = device_peaks(device_kind)
+    ridge = peaks["flops_per_s"] / peaks["hbm_bytes_per_s"]
+    t_mem = bytes_moved / peaks["hbm_bytes_per_s"]
+    t_comp = flops / peaks["flops_per_s"]
     bound = "memory" if t_mem >= t_comp else "compute"
     measured = flops / max(t_wave_s, 1e-12)
-    return {"flops": flops, "bytes": bytes_moved,
+    return {"device_kind": device_kind, "flops": flops, "bytes": bytes_moved,
             "intensity_flops_per_byte": intensity,
             "ridge_flops_per_byte": ridge,
             "frac_of_ridge": intensity / ridge,
@@ -277,13 +290,15 @@ def roofline(s, n, p, epochs, t_wave_s) -> dict:
 
 
 def run(report: Report) -> None:
+    device_kind = jax.devices()[0].device_kind
+    device_peaks(device_kind)          # no roofline for an unknown device
     s, n, p = (8, 256, 16) if QUICK else (16, 1024, 48)
     epochs = 4
     repeats = 5 if QUICK else 3
     wave = bench_wave(report, s, n, p, epochs, repeats)
     warm = bench_warm_start(report, 256 if QUICK else 512,
                             24 if QUICK else 48, repeats)
-    roof = roofline(s, n, p, epochs, wave["t_wave_s"])
+    roof = roofline(s, n, p, epochs, wave["t_wave_s"], device_kind)
     report.add("solver", "roofline", wave["t_wave_s"],
                intensity=round(roof["intensity_flops_per_byte"], 2),
                ridge=round(roof["ridge_flops_per_byte"], 1),

@@ -75,4 +75,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.kernels.runtime import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -53,14 +53,15 @@ def run(report: Report) -> None:
     script = SCRIPT.format(n=n, k=k, K=n // 4)
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"     # forced host devices; never the chip
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", script], env=env,
                        cwd=os.path.dirname(os.path.dirname(
                            os.path.abspath(__file__))),
                        capture_output=True, text=True, timeout=1800)
     if r.returncode != 0:
-        report.add("table4", f"n={n} FAILED", 0.0, error=r.stderr[-400:])
-        return
+        raise RuntimeError(f"table4 child failed (rc={r.returncode}):\n"
+                           f"{r.stderr[-2000:]}")
     d = json.loads(r.stdout.strip().splitlines()[-1])
     report.add("table4", f"n={n}/single-dev", d["t1"],
                err_pct=round(100 * d["e1"], 2), n_cells=d["n_cells"])

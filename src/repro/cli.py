@@ -629,4 +629,6 @@ def main(argv: Optional[List[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.kernels.runtime import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -283,7 +283,8 @@ def cv_cell(
                 l_est = l_shared
             coefs, _ = _solve_columns(k_full, y_cols, tr_cols, lam_c, sub_c,
                                       n_eff_cols, cfg, c0_f, l_est)
-            f_val = k_full @ coefs
+            f_val = jnp.matmul(k_full, coefs,
+                               precision=jax.lax.Precision.HIGHEST)
             vl = _val_losses(f_val, y_cols, va_cols, cfg, sub_c)
             if track_rates:
                 # validation-fold confusion counts per column: every valid

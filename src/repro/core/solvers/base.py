@@ -41,9 +41,11 @@ class BoxQPResult(NamedTuple):
 
 def _kdot(k_mat: Array, c: Array) -> Array:
     """K @ C in K's storage dtype with f32 accumulation (bf16 Gram path:
-    the MXU reads 2-byte tiles, accumulates f32 — §Perf SVM hillclimb)."""
+    the MXU reads 2-byte tiles, accumulates f32 — §Perf SVM hillclimb).
+    An f32 Gram is multiplied in full f32, not the TPU's one-pass bf16."""
     return jax.lax.dot_general(
         k_mat, c.astype(k_mat.dtype), (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
@@ -57,7 +59,8 @@ def power_iteration_l(k_mat: Array, iters: int = 32, seed: int = 0) -> Array:
         return w / jnp.maximum(jnp.linalg.norm(w), 1e-30)
 
     v = jax.lax.fori_loop(0, iters, body, v)
-    lam = v @ _kdot(k_mat, v[:, None])[:, 0]
+    lam = jnp.dot(v, _kdot(k_mat, v[:, None])[:, 0],
+                  precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(lam, 1e-12) * 1.05
 
 
@@ -148,5 +151,5 @@ def dual_objective(k_mat: Array, y: Array, c: Array) -> Array:
     """-(0.5 c^T K c - c^T y) per column — monotone diagnostics / tests."""
     if y.ndim == 1:
         y = y[:, None]
-    kc = k_mat @ c
+    kc = jnp.matmul(k_mat, c, precision=jax.lax.Precision.HIGHEST)
     return jnp.sum(c * y, axis=0) - 0.5 * jnp.sum(c * kc, axis=0)

@@ -45,7 +45,8 @@ class TrainedSVM(NamedTuple):
         gram_of = kernel_fns.cross_gram_fn(x_test, self.sv_x, self.kernel)
 
         def per_ts(gamma, coef):
-            return gram_of(gamma) @ coef
+            return jnp.matmul(gram_of(gamma), coef,
+                              precision=jax.lax.Precision.HIGHEST)
 
         t, s = self.gamma.shape
         gflat = self.gamma.reshape(-1)

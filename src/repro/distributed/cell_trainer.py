@@ -40,21 +40,6 @@ from repro.core import kernel_fns, select
 
 Array = jax.Array
 
-# jax moved shard_map out of experimental and renamed check_rep->check_vma
-# on independent schedules; resolve both by inspection, not version guessing.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                              # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SM_PARAMS = _inspect.signature(_shard_map).parameters
-_CHECK_KWARGS = ({"check_vma": False} if "check_vma" in _SM_PARAMS
-                 else {"check_rep": False} if "check_rep" in _SM_PARAMS
-                 else {})
-
-
 def _cell_train_local(x_c, y_c, tmask_c, mask_c, gammas_c, key_c,
                       lam_c, sub_c, task_c, cfg, n_lam, n_sub):
     """vmap body: one cell."""
@@ -90,11 +75,11 @@ def train_cells(
         return vbody(x_cells, y_cells, tmask_cells, mask_cells, gammas_cells, keys)
 
     spec = P(axis_names)
-    shard = _shard_map(
+    shard = jax.shard_map(
         vbody, mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec, spec),
         out_specs=(spec,) * len(wave_keys(cfg)),
-        **_CHECK_KWARGS,
+        check_vma=False,
     )
     return shard(x_cells, y_cells, tmask_cells, mask_cells, gammas_cells, keys)
 
@@ -238,7 +223,8 @@ def _cell_predict_local(xt_c, sv_c, coef_c, gamma_c, kernel: str):
     gram_of = kernel_fns.cross_gram_fn(xt_c, sv_c, kernel)
 
     def per_ts(gamma, coef):
-        return gram_of(gamma) @ coef                     # (m,)
+        return jnp.matmul(gram_of(gamma), coef,
+                          precision=jax.lax.Precision.HIGHEST)  # (m,)
 
     t, s = gamma_c.shape
     out = jax.vmap(per_ts)(gamma_c.reshape(-1), coef_c.reshape(coef_c.shape[0], -1).T)
@@ -259,7 +245,7 @@ def predict_cells(
     if mesh is None:
         return vbody(xt_cells, sv_cells, coef_cells, gamma_cells)
     spec = P(axis_names)
-    shard = _shard_map(vbody, mesh=mesh,
-                       in_specs=(spec, spec, spec, spec), out_specs=spec,
-                       **_CHECK_KWARGS)
+    shard = jax.shard_map(vbody, mesh=mesh,
+                          in_specs=(spec, spec, spec, spec), out_specs=spec,
+                          check_vma=False)
     return shard(xt_cells, sv_cells, coef_cells, gamma_cells)

@@ -78,24 +78,34 @@ Array = jax.Array
 BLOCK_COORDS = 128  # coordinates per grid step (column-block width)
 
 
-def _cd_body(k_blk, diag_ref, lo_ref, hi_ref, c_ref, g_ref, base: int,
+def _cd_body(k_blk, diag_ref, lo_ref, hi_ref, c_ref, g_ref, base,
              block: int):
     """Sweep coordinates [base, base + block) of one cell's state refs.
 
     k_blk (n, block) is the Gram column block already read into registers;
-    diag_ref (1, n); lo/hi/c/g refs (n, P).
+    diag_ref (1, n); lo/hi/c/g refs (n, P).  Mosaic cannot slice a value at
+    a dynamic lane offset, so coordinate t's Gram column and diagonal entry
+    are picked with a one-hot lane select + lane sum (exact: one nonzero
+    term), and the diagonal block is read at a 128-aligned offset.
     """
+    diag = diag_ref[:, pl.ds(pl.multiple_of(base, block), block)]  # (1, block)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+
     def body(t, _):
         i = base + t
-        d = jnp.maximum(diag_ref[0, i], 1e-12)
-        ci = pl.load(c_ref, (pl.dslice(i, 1), slice(None)))      # (1, P)
-        gi = pl.load(g_ref, (pl.dslice(i, 1), slice(None)))
-        li = pl.load(lo_ref, (pl.dslice(i, 1), slice(None)))
-        hi = pl.load(hi_ref, (pl.dslice(i, 1), slice(None)))
+        hit = lane == t
+        d = jnp.maximum(jnp.sum(jnp.where(hit, diag, 0.0), axis=1,
+                                keepdims=True), 1e-12)            # (1, 1)
+        row = (pl.ds(i, 1), slice(None))
+        ci = c_ref[row]                                           # (1, P)
+        gi = g_ref[row]
+        li = lo_ref[row]
+        hi = hi_ref[row]
         target = jnp.clip(ci - gi / d, li, hi)
         delta = target - ci                                       # (1, P)
-        pl.store(c_ref, (pl.dslice(i, 1), slice(None)), target)
-        k_col = jax.lax.dynamic_slice(k_blk, (0, t), (k_blk.shape[0], 1))  # (n, 1)
+        c_ref[row] = target
+        k_col = jnp.sum(jnp.where(hit, k_blk, 0.0), axis=1,
+                        keepdims=True)                            # (n, 1)
         g_ref[...] += k_col * delta
         return 0
 
@@ -174,7 +184,7 @@ def cd_wave_epoch_pallas(k_mats: Array, c: Array, g: Array, lo: Array,
     kwargs = {}
     if not interpret:  # Mosaic: slots are parallel, the block sweep is not
         from jax.experimental.pallas import tpu as pltpu
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
     c_out, g_out = pl.pallas_call(
         functools.partial(_cd_wave_kernel, block=BLOCK_COORDS),

@@ -84,7 +84,8 @@ def _d2_tile(x_ref, z_ref) -> Array:
     x = x_ref[...].astype(jnp.float32)          # (bn, d)
     z = z_ref[...].astype(jnp.float32)          # (bm, d)
     cross = jax.lax.dot_general(                # MXU: (bn, d) x (bm, d)^T
-        x, z, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, z, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32
     )
     xx = jnp.sum(x * x, axis=-1)[:, None]
     zz = jnp.sum(z * z, axis=-1)[None, :]

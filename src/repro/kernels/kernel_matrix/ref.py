@@ -10,8 +10,11 @@ Array = jax.Array
 def sq_dists_ref(x: Array, z: Array, symmetric: bool = False) -> Array:
     x = x.astype(jnp.float32)
     z = z.astype(jnp.float32)
+    # full f32 cross term: the TPU's default one-pass bf16 matmul makes the
+    # GEMM-form D² cancel badly for nearby points
+    cross = jnp.matmul(x, z.T, precision=jax.lax.Precision.HIGHEST)
     d2 = jnp.maximum(
-        jnp.sum(x * x, -1)[:, None] + jnp.sum(z * z, -1)[None, :] - 2.0 * (x @ z.T), 0.0
+        jnp.sum(x * x, -1)[:, None] + jnp.sum(z * z, -1)[None, :] - 2.0 * cross, 0.0
     )
     if symmetric:
         # match the Pallas upper-triangle + mirror contract bitwise

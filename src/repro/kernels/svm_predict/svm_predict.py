@@ -38,6 +38,7 @@ def _predict_kernel(x_ref, sv_ref, c_ref, gamma_ref, o_ref, *, kind: str):
     sv = sv_ref[...].astype(jnp.float32)   # (bs, d)
     gamma = gamma_ref[0, 0]
     cross = jax.lax.dot_general(x, sv, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32)
     d2 = jnp.maximum(jnp.sum(x * x, -1)[:, None] + jnp.sum(sv * sv, -1)[None, :]
                      - 2.0 * cross, 0.0)
@@ -48,6 +49,7 @@ def _predict_kernel(x_ref, sv_ref, c_ref, gamma_ref, o_ref, *, kind: str):
     else:
         raise ValueError(kind)
     partial = jnp.dot(k_tile, c_ref[...].astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST,
                       preferred_element_type=jnp.float32)  # (bt, P)
 
     @pl.when(j == 0)
@@ -93,6 +95,7 @@ def _predict_cells_kernel(x_ref, sv_ref, c_ref, g_ref, o_ref, *, kind: str):
     sv = sv_ref[0].astype(jnp.float32)     # (bs, d)
     c = c_ref[0].astype(jnp.float32)       # (bs, P)
     cross = jax.lax.dot_general(x, sv, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32)
     d2 = jnp.maximum(jnp.sum(x * x, -1)[:, None] + jnp.sum(sv * sv, -1)[None, :]
                      - 2.0 * cross, 0.0)
@@ -106,6 +109,7 @@ def _predict_cells_kernel(x_ref, sv_ref, c_ref, g_ref, o_ref, *, kind: str):
         else:
             raise ValueError(kind)
         cols.append(jnp.dot(k_tile, c[:, p:p + 1],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32))
     partial = jnp.concatenate(cols, axis=1)  # (bt, P)
 

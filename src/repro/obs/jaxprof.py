@@ -8,14 +8,16 @@ through the CLI), wave launches are bracketed with
 one step in the captured trace, and :func:`start`/:func:`stop` drive the
 device trace capture itself.
 
-Everything here degrades to a no-op when no directory is configured or the
-installed jax lacks the profiler — observability must never be the thing
-that crashes serving.
+With no directory configured every hook is a no-op.  With one configured a
+failed capture raises: a profile that was asked for and silently not taken
+would be read as "no device time".
 """
 from __future__ import annotations
 
 import contextlib
 from typing import Optional
+
+import jax
 
 # process-global profile directory; None = all hooks are no-ops
 _PROFILE_DIR: Optional[str] = None
@@ -38,16 +40,11 @@ def active() -> bool:
 
 def start() -> bool:
     """Begin a device trace capture into the configured directory.
-    Returns False (no-op) when unconfigured, already active, or the
-    profiler is unavailable on this jax build."""
+    Returns False (no-op) when unconfigured or already active."""
     global _ACTIVE
     if _PROFILE_DIR is None or _ACTIVE:
         return False
-    try:
-        import jax
-        jax.profiler.start_trace(_PROFILE_DIR)
-    except Exception:
-        return False
+    jax.profiler.start_trace(_PROFILE_DIR)
     _ACTIVE = True
     return True
 
@@ -57,11 +54,7 @@ def stop() -> bool:
     if not _ACTIVE:
         return False
     _ACTIVE = False
-    try:
-        import jax
-        jax.profiler.stop_trace()
-    except Exception:
-        return False
+    jax.profiler.stop_trace()
     return True
 
 
@@ -75,8 +68,4 @@ def step(name: str, num: int):
     """
     if _PROFILE_DIR is None:
         return contextlib.nullcontext()
-    try:
-        import jax
-        return jax.profiler.StepTraceAnnotation(name, step_num=num)
-    except Exception:
-        return contextlib.nullcontext()
+    return jax.profiler.StepTraceAnnotation(name, step_num=num)

@@ -210,6 +210,7 @@ def _assign_block_jax(chunk: Array, centers: Array) -> Array:
     xx = jnp.sum(chunk * chunk, axis=1)
     cc = jnp.sum(centers * centers, axis=1)
     cross = jax.lax.dot_general(chunk, centers, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32)
     d2 = xx[:, None] + cc[None, :] - 2.0 * cross
     return jnp.argmin(d2, axis=1).astype(jnp.int32)
@@ -226,6 +227,7 @@ def _assign_kernel(x_ref, c_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)              # (BLOCK_ROWS, d)
     c = c_ref[...].astype(jnp.float32)              # (C_pad, d) resident
     cross = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32)
     xx = jnp.sum(x * x, axis=-1)[:, None]
     cc = jnp.sum(c * c, axis=-1)[None, :]

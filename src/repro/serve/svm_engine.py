@@ -159,7 +159,8 @@ def _decide_cells(d2: Array, gammas: Array, coefs: Array, kernel: str) -> Array:
 
     def cell(d2_c, g_c, co_c):
         def col(g, co):
-            return km_ops.gram_from_d2(d2_c, g, kind=kernel) @ co
+            return jnp.matmul(km_ops.gram_from_d2(d2_c, g, kind=kernel), co,
+                              precision=jax.lax.Precision.HIGHEST)
 
         return jax.vmap(col)(g_c, co_c.T).T
 

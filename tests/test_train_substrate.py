@@ -214,9 +214,7 @@ class TestCompression:
             def body(gl, el):
                 out, new_err = ef_psum(gl[0], el[0], "pod")
                 return out[None], new_err[None]
-            # one shard_map version resolver for the whole repo
-            from repro.distributed.cell_trainer import _shard_map as sm
-            f = jax.jit(sm(body, mesh=mesh,
+            f = jax.jit(jax.shard_map(body, mesh=mesh,
                 in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod"))))
             out, err = f(g, jnp.zeros_like(g))
             want = np.mean(np.asarray(g), axis=0)
