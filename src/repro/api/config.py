@@ -106,11 +106,15 @@ off with :func:`split_obs_keys`)
                        (counters/gauges/latency histograms/quantile
                        sketches, schema ``repro.obs.metrics.v1``) to this
                        JSONL file when the CLI stage exits.
-  PROFILE_DIR          path   capture ``jax.profiler`` device traces around
-                       wave launches into this directory (each wave is a
-                       ``StepTraceAnnotation`` step; ``cv.d2``/
-                       ``cv.epilogue``/``cv.solve`` named scopes label the
-                       jitted CV internals).
+  PROFILE_DIR          path   capture one ``jax.profiler`` device trace of
+                       the whole CLI command into this directory (a
+                       ``.xplane.pb``): each training and serving wave is a
+                       ``StepTraceAnnotation`` step and each span of the
+                       tracer an annotation on the host plane.  Implies
+                       TRACE=1 unless TRACE=0 is set explicitly.  The op
+                       events carry no named scope; ``repro.obs.jaxprof.
+                       scope_tables`` maps them to ``cv.d2``/
+                       ``cv.epilogue``/``cv.solve``.
 
 Accepted for liquidSVM compatibility, no effect here
   DISPLAY, THREADS

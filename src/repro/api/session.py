@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import obs
 from repro.cells.builder import CellPlan
 from repro.core import cv as cv_mod
 from repro.core import grids, kernel_fns
@@ -238,7 +239,6 @@ class TrainResult:
                  "columns_resolved": 0, "resolve_calls": 0,
                  "solver_iters": 0}
 
-        from repro import obs
         m_resolved = obs.metrics.counter("select.columns_resolved")
         # group moved cells by winning gamma-grid INDEX: every cell in a
         # group re-solves in ONE vmapped launch, not one jit call per
@@ -448,23 +448,24 @@ class SelectResult:
         (``SVMEngine.swap_bank`` accepts strictly newer versions only).
         """
         from repro.serve.model_bank import _FAR, ModelBank
-        n_slots = self.packed.n_slots
-        d = self.x_cells.shape[2]
-        centers = np.full((n_slots, d), _FAR, np.float32)
-        for s, cid in enumerate(self.packed.order):
-            if cid >= 0:
-                centers[s] = self.plan.centers[cid]
-        routing = "overlap" if self.config.cell_method == "overlap" \
-            else "nearest"
-        return ModelBank.from_cells(
-            self.x_cells, self.mask_cells, self.coefs, self.gamma, centers,
-            kernel=self.config.kernel, drop_tol=drop_tol, dtype=dtype,
-            dedup=dedup,
-            feat_mean=np.asarray(self.scaler.mean, np.float32),
-            feat_std=np.asarray(self.scaler.std, np.float32),
-            classes=self.tasks.classes, pairs=self.tasks.pairs,
-            scenario=self.config.scenario, default_sub=self.default_sub,
-            routing=routing, version=version)
+        with obs.tracer.span("session.bank"):
+            n_slots = self.packed.n_slots
+            d = self.x_cells.shape[2]
+            centers = np.full((n_slots, d), _FAR, np.float32)
+            for s, cid in enumerate(self.packed.order):
+                if cid >= 0:
+                    centers[s] = self.plan.centers[cid]
+            routing = "overlap" if self.config.cell_method == "overlap" \
+                else "nearest"
+            return ModelBank.from_cells(
+                self.x_cells, self.mask_cells, self.coefs, self.gamma,
+                centers, kernel=self.config.kernel, drop_tol=drop_tol,
+                dtype=dtype, dedup=dedup,
+                feat_mean=np.asarray(self.scaler.mean, np.float32),
+                feat_std=np.asarray(self.scaler.std, np.float32),
+                classes=self.tasks.classes, pairs=self.tasks.pairs,
+                scenario=self.config.scenario, default_sub=self.default_sub,
+                routing=routing, version=version)
 
     # ------------------------------------------------------ persistence
     _ARRAYS = ("x_cells", "mask_cells", "coefs", "gamma", "lam", "tau",
@@ -547,7 +548,6 @@ class SVM:
                                           split_serve_keys)
             config_keys, key_obs = split_obs_keys(config_keys)
             if key_obs:
-                from repro import obs
                 obs.configure(**key_obs)
             config_keys, key_emb = split_embed_keys(config_keys)
             if key_emb:
@@ -587,16 +587,17 @@ class SVM:
             y = x.labels_vector(cfg.chunk_size)
 
         raw_src: ChunkSource = as_source(x)
-        if cfg.scale:
-            scaler = Scaler.fit_stream(raw_src, cfg.chunk_size)
-        else:
-            scaler = Scaler(mean=np.zeros(raw_src.dim, np.float32),
-                            std=np.ones(raw_src.dim, np.float32))
-        if isinstance(raw_src, ArraySource):     # in-memory: scale once
-            xs_src: ChunkSource = ArraySource(
-                scaler.transform(raw_src.materialize()))
-        else:                                    # out-of-core: scale lazily
-            xs_src = ScaledSource(raw_src, scaler.mean, scaler.std)
+        with obs.tracer.span("session.scale"):
+            if cfg.scale:
+                scaler = Scaler.fit_stream(raw_src, cfg.chunk_size)
+            else:
+                scaler = Scaler(mean=np.zeros(raw_src.dim, np.float32),
+                                std=np.ones(raw_src.dim, np.float32))
+            if isinstance(raw_src, ArraySource):     # in-memory: scale once
+                xs_src: ChunkSource = ArraySource(
+                    scaler.transform(raw_src.materialize()))
+            else:                                    # out-of-core: lazily
+                xs_src = ScaledSource(raw_src, scaler.mean, scaler.std)
         n, d = xs_src.shape
 
         scenario = "weighted" if cfg.scenario in ("weighted", "npsvm") \
@@ -607,10 +608,11 @@ class SVM:
         n_dev = 1
         if self.mesh is not None and self.mesh_axes is not None:
             n_dev = int(np.prod([self.mesh.shape[a] for a in self.mesh_axes]))
-        plan: CellPlan = build_cells_stream(
-            xs_src, cell_size=cfg.cell_size, method=cfg.cell_method,
-            seed=cfg.seed, chunk_size=cfg.chunk_size)
-        packed: PackedCells = pack_cells(plan, n_dev)
+        with obs.tracer.span("session.cells"):
+            plan: CellPlan = build_cells_stream(
+                xs_src, cell_size=cfg.cell_size, method=cfg.cell_method,
+                seed=cfg.seed, chunk_size=cfg.chunk_size)
+            packed: PackedCells = pack_cells(plan, n_dev)
 
         k = plan.k_max
         n_slots = packed.n_slots
@@ -743,9 +745,10 @@ class SVM:
         if self.train_result is None:
             raise RuntimeError("call train() before select()")
         merged = {**self.select_kwargs, **rule_kwargs}
-        self.select_result = self.train_result.select(
-            rule or self.select_rule, mesh=self.mesh,
-            mesh_axes=self.mesh_axes, **merged)
+        with obs.tracer.span("session.select"):
+            self.select_result = self.train_result.select(
+                rule or self.select_rule, mesh=self.mesh,
+                mesh_axes=self.mesh_axes, **merged)
         return self.select_result
 
     # -------------------------------------------------------------- test

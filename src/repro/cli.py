@@ -106,12 +106,14 @@ def _emit(payload: dict) -> None:
 def _setup_obs(pairs: dict) -> dict:
     """Split TRACE/METRICS_OUT/PROFILE_DIR off a ``-S`` key dict and apply
     them to the process-global ``repro.obs`` instruments; returns the
-    remaining pairs for the stage's own key handling."""
+    remaining pairs for the stage's own key handling.  With PROFILE_DIR the
+    device trace capture starts here; :func:`main` stops it on exit."""
     from repro.api.config import split_obs_keys
     rest, obs_kw = split_obs_keys(pairs)
     if obs_kw:
         from repro import obs
         obs.configure(**obs_kw)
+        obs.jaxprof.start()
     return rest
 
 
@@ -620,12 +622,15 @@ def main(argv: Optional[List[str]] = None) -> int:
     from repro.embed.source import EmbedCacheError
     from repro.pipeline.dataset import DataSourceError
     from repro.train.checkpoint import CheckpointCorruptError
+    from repro.obs import jaxprof
     try:
         return args.fn(args)
     except (ConfigError, DataSourceError, CheckpointCorruptError,
             EmbedCacheError) as e:
         print(f"error: {e}", file=sys.stderr)
         return 2
+    finally:
+        jaxprof.stop()
 
 
 if __name__ == "__main__":

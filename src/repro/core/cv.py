@@ -110,6 +110,8 @@ class CVSelected(NamedTuple):
     fa_grid: Array      # (n_gamma, n_tasks, n_lam, n_sub) validation false-
                         # alarm COUNTS (hinge + keep_surface only, else 0)
     det_grid: Array     # (n_gamma, n_tasks, n_lam, n_sub) detection counts
+    iters: Array        # (n_gamma, n_folds) box-QP iterations per solve
+                        # (0 for the direct ls/expectile solves)
 
 
 def make_fold_masks(
@@ -245,8 +247,9 @@ def cv_cell(
     track_rates = cfg.keep_surface and cfg.solver == "hinge"
     # ONE D² for the whole gamma scan: the O(n²d) MXU cross term is hoisted
     # out of the lax.scan; each scan step replays only the O(n²) epilogue.
-    # named_scope markers label the D²-vs-epilogue-vs-solve split in a
-    # PROFILE_DIR device trace (host timing cannot see inside this jit).
+    # named_scope markers label the D²-vs-epilogue-vs-solve split in the
+    # compiled program's HLO metadata; the trace's op events carry no
+    # scope, so ``obs.jaxprof.scope_tables`` joins them on instruction name.
     if use_d2:
         with jax.named_scope("cv.d2"):
             cg = kernel_fns.CachedGram.build(x, name=cfg.kernel)
@@ -281,8 +284,8 @@ def cv_cell(
                 l_est = qp.power_iteration_l(k_full * mt[:, None] * mt[None, :])
             else:
                 l_est = l_shared
-            coefs, _ = _solve_columns(k_full, y_cols, tr_cols, lam_c, sub_c,
-                                      n_eff_cols, cfg, c0_f, l_est)
+            coefs, iters = _solve_columns(k_full, y_cols, tr_cols, lam_c,
+                                          sub_c, n_eff_cols, cfg, c0_f, l_est)
             f_val = jnp.matmul(k_full, coefs,
                                precision=jax.lax.Precision.HIGHEST)
             vl = _val_losses(f_val, y_cols, va_cols, cfg, sub_c)
@@ -296,11 +299,11 @@ def cv_cell(
                 det = jnp.sum((pred_pos & (y_cols > 0)).astype(jnp.float32), 0)
             else:
                 fa = det = jnp.zeros_like(vl)
-            return vl, fa, det, coefs
+            return vl, fa, det, coefs, iters
 
         with jax.named_scope("cv.solve"):
-            vl, fa, det, coefs = jax.vmap(per_fold)(train_folds, val_folds,
-                                                    c0_all)
+            vl, fa, det, coefs, iters = jax.vmap(per_fold)(
+                train_folds, val_folds, c0_all)
         vl_mean = jnp.mean(vl, axis=0)                                  # (P,)
         fa_tls = jnp.sum(fa, axis=0).reshape(n_tasks, n_lam, n_sub)
         det_tls = jnp.sum(det, axis=0).reshape(n_tasks, n_lam, n_sub)
@@ -319,7 +322,7 @@ def cv_cell(
         best_g = jnp.where(improved, gamma, best_g)
         best_l = jnp.where(improved, lam_c[flat_cols.reshape(-1)].reshape(n_tasks, n_sub), best_l)
         carry = (best_val, best_cfs, best_g, best_l, coefs)             # warm start
-        return carry, (vl_tls, fa_tls, det_tls)
+        return carry, (vl_tls, fa_tls, det_tls, iters)
 
     init = (
         jnp.full((n_tasks, n_sub), jnp.inf, jnp.float32),
@@ -328,7 +331,8 @@ def cv_cell(
         jnp.zeros((n_tasks, n_sub), jnp.float32),
         jnp.zeros((cfg.n_folds, n, p), jnp.float32),
     )
-    (best_val, best_cfs, best_g, best_l, _), (vl_all, fa_all, det_all) = \
+    (best_val, best_cfs, best_g, best_l, _), (vl_all, fa_all, det_all,
+                                              iters_all) = \
         jax.lax.scan(per_gamma, init, gammas)
 
     sub_grid = sub_c[:n_sub]
@@ -341,7 +345,8 @@ def cv_cell(
 
     return CVSelected(coefs=best_cfs, gamma=best_g, lam=best_l, tau=tau,
                       weight=weight, val_loss=best_val, val_grid=vl_all,
-                      fa_grid=fa_all, det_grid=det_all)
+                      fa_grid=fa_all, det_grid=det_all,
+                      iters=iters_all.astype(jnp.int32))
 
 
 def _solve_columns_at_core(x, y_tasks, task_mask, mask, gamma, lam_cols,

@@ -50,7 +50,7 @@ def _cell_train_local(x_c, y_c, tmask_c, mask_c, gammas_c, key_c,
     out = (combined, sel.gamma, sel.lam, sel.tau, sel.val_loss)
     if cfg.keep_surface:
         out = out + (sel.val_grid, sel.fa_grid, sel.det_grid)
-    return out
+    return out + (sel.iters,)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "n_lam", "n_sub", "mesh", "axis_names"))
@@ -67,7 +67,10 @@ def train_cells(
     mesh: Mesh | None = None,
     axis_names: Tuple[str, ...] | None = None,
 ):
-    """Returns (coefs (n_slots, k, T, S), gamma/lam/tau/val (n_slots, T, S))."""
+    """Returns the :func:`wave_keys` arrays (coefs (n_slots, k, T, S),
+    gamma/lam/tau/val (n_slots, T, S), the surface with
+    ``cfg.keep_surface``), then the box-QP iteration counts
+    (n_slots, n_gamma, n_folds): a diagnostic, not a wave key."""
     body = functools.partial(_cell_train_local, lam_c=lam_c, sub_c=sub_c,
                              task_c=task_c, cfg=cfg, n_lam=n_lam, n_sub=n_sub)
     vbody = jax.vmap(body)
@@ -78,13 +81,35 @@ def train_cells(
     shard = jax.shard_map(
         vbody, mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec, spec),
-        out_specs=(spec,) * len(wave_keys(cfg)),
+        out_specs=(spec,) * (len(wave_keys(cfg)) + 1),
         check_vma=False,
     )
     return shard(x_cells, y_cells, tmask_cells, mask_cells, gammas_cells, keys)
 
 
 # ------------------------------------------------------------------ waves
+def fista_counts(iters: np.ndarray, mask: np.ndarray, max_iters: int,
+                 n_dev: int = 1) -> dict:
+    """One wave's box-QP counts from ``train_cells``' iteration output.
+
+    ``iters`` (slots, n_gamma, n_folds); ``mask`` (slots, k) marks real
+    rows, so a slot with none is padding.  ``solves``, ``iters`` and
+    ``capped`` (stopped at ``max_iters``) count real slots only.
+    ``lane_iters`` is what the batched loop ran: the slots are split
+    evenly over ``n_dev`` devices in order, and on each device, for each
+    gamma, every (slot, fold) lane it carries, padding included, runs as
+    many iterations as its slowest lane.
+    """
+    real = np.asarray(mask).reshape(mask.shape[0], -1).sum(axis=1) > 0
+    it = np.asarray(iters, np.int64)
+    s, g, f = it.shape
+    per_dev = it.reshape(n_dev, s // n_dev, g, f)
+    lane = int(per_dev.max(axis=(1, 3)).sum()) * (s // n_dev) * f
+    mine = it[real]
+    return {"solves": int(mine.size), "iters": int(mine.sum()),
+            "capped": int((mine >= max_iters).sum()), "lane_iters": lane}
+
+
 _WAVE_KEYS = ("coefs", "gamma", "lam", "tau", "val")
 _SURFACE_KEYS = ("surf_loss", "surf_fa", "surf_det")
 
@@ -141,17 +166,22 @@ def train_cells_waves(
     loaded.
     """
     from repro import obs
+    from repro.obs import jaxprof
     from repro.testing import faults
     from repro.train import checkpoint as ckpt_mod
 
     m_solved = obs.metrics.counter("train.waves_solved")
     m_restored = obs.metrics.counter("train.waves_restored")
     m_corrupt = obs.metrics.counter("train.corrupt_waves")
+    box_qp = cfg.solver in ("hinge", "quantile")
+    m_fista = {k: obs.metrics.counter("train.fista." + k)
+               for k in ("solves", "iters", "capped", "lane_iters")}
 
     keys_out = wave_keys(cfg)
     if wave_size is None or wave_size >= n_slots:
         wave_size = n_slots
     assert wave_size > 0
+    n_dev = 1
     if mesh is not None and axis_names is not None:
         n_dev = int(np.prod([mesh.shape[a] for a in axis_names]))
         assert wave_size % n_dev == 0, (
@@ -194,10 +224,23 @@ def train_cells_waves(
                 arrays = stage(lo, lo + wave_size)
             with obs.tracer.span("train.wave.solve") as sp:
                 sp.set(wave=w, slots=wave_size, cd_polish=cfg.cd_polish)
-                res = train_cells(*[jnp.asarray(a) for a in arrays],
-                                  lam_c, sub_c, task_c, cfg, n_lam, n_sub,
-                                  mesh=mesh, axis_names=axis_names)
-                res = tuple(np.asarray(r) for r in res)
+                args = [jnp.asarray(a) for a in arrays]
+                if obs.tracer.enabled:
+                    jaxprof.note(train_cells, *args, lam_c, sub_c, task_c,
+                                 cfg, n_lam, n_sub, mesh=mesh,
+                                 axis_names=axis_names)
+                with jaxprof.step("train_wave", w):
+                    res = train_cells(*args, lam_c, sub_c, task_c, cfg,
+                                      n_lam, n_sub, mesh=mesh,
+                                      axis_names=axis_names)
+                    res = tuple(np.asarray(r) for r in res)
+                res, iters = res[:-1], res[-1]
+                if box_qp:
+                    counts = fista_counts(iters, arrays[3], cfg.max_iters,
+                                          n_dev)
+                    for k, v in counts.items():
+                        m_fista[k].inc(v)
+                    sp.set(**{"fista_" + k: v for k, v in counts.items()})
             m_solved.inc()
             faults.fire("trainer.wave.solved", wave=w)
             if ckpt_dir is not None:

@@ -14,14 +14,20 @@ config-key surface (see ``repro.api.config``):
                          (schema ``repro.obs.trace.v1``; implies TRACE=1
                          unless TRACE=0 is given explicitly)
   ``METRICS_OUT=<path>`` write the metrics registry as JSONL on exit
-  ``PROFILE_DIR=<path>`` capture ``jax.profiler`` traces around wave
-                         launches into this directory
+  ``PROFILE_DIR=<path>`` capture a ``jax.profiler`` device trace of the
+                         whole CLI command into this directory; each wave
+                         is a profiler step and each span a host-plane
+                         annotation (implies TRACE=1 unless TRACE=0 is
+                         given explicitly)
 
 Everything is off by default and each disabled hook costs one attribute
-test on the hot path.  The consumer layer on top of these signals —
-quantile sketches (``obs.sketch``), SLO burn rates (``obs.slo``) and the
-drift-triggered refresh loop (``serve.monitor``) — reads the same global
-instruments.
+test on the hot path.  The wave scheduler counts its FISTA solves
+(``train.fista.*``: one small host reduction per wave) whatever the
+tracer's state, and :func:`jaxprof.scope_tables` maps a capture's device
+ops to the program's named scopes.  The consumer layer on top of these
+signals — quantile sketches (``obs.sketch``), SLO burn rates
+(``obs.slo``) and the drift-triggered refresh loop (``serve.monitor``) —
+reads the same global instruments.
 """
 from __future__ import annotations
 
@@ -48,6 +54,7 @@ __all__ = [
 # process-global instruments — the default sinks for every instrumented site
 tracer = Tracer()
 metrics = MetricsRegistry()
+jaxprof.bind_tracer(tracer)
 
 _METRICS_OUT: Optional[str] = None
 _TRACE_OUT: Optional[str] = None
@@ -72,6 +79,10 @@ def configure(trace: Optional[bool] = None,
         _METRICS_OUT = metrics_out or None
     if profile_dir is not None:
         jaxprof.configure(profile_dir or None)
+        # a capture without the program's spans names no stage: PROFILE_DIR
+        # implies TRACE=1 unless the same call says TRACE=0 explicitly
+        if profile_dir and trace is None:
+            tracer.enabled = True
 
 
 def metrics_out() -> Optional[str]:
@@ -113,3 +124,4 @@ def reset() -> None:
     _METRICS_OUT = None
     _TRACE_OUT = None
     jaxprof.configure(None)
+    jaxprof.clear_programs()

@@ -75,6 +75,14 @@ WELL_KNOWN: Dict[str, str] = {
     "train.waves_restored": "counter: training waves restored from disk",
     "train.corrupt_waves": "counter: wave checkpoints failing verification "
                            "(re-solved, not loaded)",
+    "train.fista.solves": "counter: box-QP solves of real (non-padding) "
+                          "slots, one per slot, gamma and fold",
+    "train.fista.iters": "counter: FISTA iterations of those solves, summed",
+    "train.fista.capped": "counter: those solves that stopped at max_iters",
+    "train.fista.lane_iters": "counter: (slot, fold) lane-iterations the "
+                              "batched loop ran: per device and gamma, the "
+                              "slowest lane's count times the lanes it "
+                              "carried (padding included)",
     "select.columns_resolved": "counter: select-stage targeted re-solves",
     "checkpoint.saves": "counter: checkpoint steps written",
     "checkpoint.restores": "counter: checkpoint steps restored",

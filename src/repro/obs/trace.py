@@ -24,17 +24,27 @@ Design constraints (these are serve-hot-path sites):
     long-running serve loop cannot grow memory by being observed
     (``dropped`` counts what the ring evicted).
 
+While the tracer is enabled every span, and every region a ``record`` site
+brackets with :meth:`Tracer.annotate`, also opens a
+``jax.profiler.TraceAnnotation`` of the same name: under a running
+``jax.profiler`` capture the program's spans then sit on the trace's host
+plane, on the same clock as the device's ops.
+
 Known sites (grep ``tracer.span\\|tracer.record`` for the authoritative
-list): ``serve.route`` ``serve.pack`` ``serve.dispatch`` ``serve.device``
-``serve.collect`` ``train.wave.stage`` ``train.wave.solve``
+list): ``session.scale`` ``session.cells`` ``session.select``
+``session.bank`` ``train.wave.stage`` ``train.wave.solve``
 ``train.wave.restore`` ``train.wave.checkpoint`` ``select.resolve``
-``checkpoint.save`` ``checkpoint.restore``.
+``checkpoint.save`` ``checkpoint.restore`` ``serve.route`` ``serve.pack``
+``serve.dispatch`` ``serve.device`` ``serve.collect`` ``serve.embed``
+``serve.drift_refresh`` ``embed.forward`` ``embed.pool``.
 """
 from __future__ import annotations
 
 import json
 import time
 from typing import Any, Callable, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 TRACE_SCHEMA = "repro.obs.trace.v1"
 
@@ -151,15 +161,17 @@ class Span:
 
 
 class _LiveSpan:
-    """Context manager for an enabled tracer; records itself on exit."""
+    """Context manager for an enabled tracer; records itself on exit and
+    brackets its body with a profiler annotation of the same name."""
 
-    __slots__ = ("_tracer", "name", "t0", "attrs")
+    __slots__ = ("_tracer", "name", "t0", "attrs", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str):
         self._tracer = tracer
         self.name = name
         self.attrs: Optional[Dict[str, Any]] = None
         self.t0 = 0.0
+        self._ann = TraceAnnotation(name)
 
     def set(self, **attrs: Any) -> "_LiveSpan":
         if self.attrs is None:
@@ -169,6 +181,7 @@ class _LiveSpan:
         return self
 
     def __enter__(self) -> "_LiveSpan":
+        self._ann.__enter__()
         self._tracer._depth += 1
         self.t0 = self._tracer._clock()
         return self
@@ -178,6 +191,7 @@ class _LiveSpan:
         tr = self._tracer
         tr._depth -= 1
         tr._emit(Span(self.name, self.t0, t1, tr._depth, self.attrs))
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -219,6 +233,15 @@ class Tracer:
         if not self.enabled:
             return
         self._emit(Span(name, t0, t1, self._depth, None))
+
+    def annotate(self, name: str):
+        """Bracket the region a :meth:`record` site times, so that it shows
+        up on a profiler capture's host plane too (its timestamps were read
+        by the caller and cannot be placed there afterwards).  Records
+        nothing itself; :data:`NULL_SPAN` when disabled (no allocation)."""
+        if not self.enabled:
+            return NULL_SPAN
+        return TraceAnnotation(name)
 
     def _emit(self, span: Span) -> None:
         self.spans.append(span)

@@ -528,34 +528,37 @@ class SVMEngine:
                 "a wave is already in flight - call finish_step() first")
         faults.fire("engine.begin_step")
         t_begin = float(self._clock())
-        counts = np.asarray([len(q) for q in self._queues], np.int64)
-        plan = plan_wave(counts, row_bucket=self.row_bucket,
-                         slot_bucket=self.slot_bucket)
-        if plan.n_requests == 0:
-            return False
-        queues, self._queues = self._queues, [
-            [] for _ in range(self.bank.n_cells)]
-        d = self._centers.shape[1]
-        xt = np.zeros((plan.n_slots, plan.m_pad, d), np.float32)
-        slot_entries: List[List[Tuple[int, int]]] = []
-        now = float(self._clock())
-        ages: List[float] = []
-        for s in range(plan.n_slots):
-            cid, off, take = (int(plan.slot_cell[s]), int(plan.slot_off[s]),
-                              int(plan.slot_take[s]))
-            entries: List[Tuple[int, int]] = []
-            if cid >= 0:
-                for r, (rid, part, row) in enumerate(queues[cid][off:off + take]):
-                    xt[s, r] = row
-                    entries.append((rid, part))
-                    ages.append((now - self._reqs[rid].ts) * 1e3)
-            slot_entries.append(entries)
-        t_pack = float(self._clock())
+        with self._tracer.annotate("serve.pack"):
+            counts = np.asarray([len(q) for q in self._queues], np.int64)
+            plan = plan_wave(counts, row_bucket=self.row_bucket,
+                             slot_bucket=self.slot_bucket)
+            if plan.n_requests == 0:
+                return False
+            queues, self._queues = self._queues, [
+                [] for _ in range(self.bank.n_cells)]
+            d = self._centers.shape[1]
+            xt = np.zeros((plan.n_slots, plan.m_pad, d), np.float32)
+            slot_entries: List[List[Tuple[int, int]]] = []
+            now = float(self._clock())
+            ages: List[float] = []
+            for s in range(plan.n_slots):
+                cid, off, take = (int(plan.slot_cell[s]), int(plan.slot_off[s]),
+                                  int(plan.slot_take[s]))
+                entries: List[Tuple[int, int]] = []
+                if cid >= 0:
+                    for r, (rid, part, row) in enumerate(queues[cid][off:off + take]):
+                        xt[s, r] = row
+                        entries.append((rid, part))
+                        ages.append((now - self._reqs[rid].ts) * 1e3)
+                slot_entries.append(entries)
+            t_pack = float(self._clock())
 
-        cell_idx = np.maximum(plan.slot_cell, 0)     # padding slots: ignored rows
-        with jaxprof.step("serve_wave", self.wave_stats.total):
-            dec = self._evaluate(jnp.asarray(xt), jnp.asarray(cell_idx), plan)
-        t_disp = float(self._clock())
+        with self._tracer.annotate("serve.dispatch"):
+            cell_idx = np.maximum(plan.slot_cell, 0)  # padding slots: ignored rows
+            with jaxprof.step("serve_wave", self.wave_stats.total):
+                dec = self._evaluate(jnp.asarray(xt), jnp.asarray(cell_idx),
+                                     plan)
+            t_disp = float(self._clock())
         rec = self._record_wave(plan, ages,
                                 pack_ms=(t_pack - t_begin) * 1e3,
                                 dispatch_ms=(t_disp - t_pack) * 1e3)
@@ -591,26 +594,28 @@ class SVMEngine:
         plan, slot_entries, dec, t, s_count, version, rec = self._inflight
         self._inflight = None
         t_wait = float(self._clock())
-        dec = np.asarray(dec)
-        t_dev = float(self._clock())
+        with self._tracer.annotate("serve.device"):
+            dec = np.asarray(dec)
+            t_dev = float(self._clock())
         results: Dict[int, np.ndarray] = {}
         done_ts: List[Tuple[int, float]] = []
-        for s, entries in enumerate(slot_entries):
-            for r, (rid, part) in enumerate(entries):
-                req = self._reqs[rid]
-                req.vals[part] = dec[s, r].reshape(t, s_count)
-                req.left -= 1
-                if req.left == 0:
-                    out = req.weights[0] * req.vals[0]
-                    for p in range(1, len(req.vals)):
-                        out = out + req.weights[p] * req.vals[p]
-                    results[rid] = out
-                    del self._reqs[rid]
-                    done_ts.append((rid, req.ts))
-                    self.served_version[rid] = version
-                    while len(self.served_version) > _SERVED_VERSION_CAP:
-                        self.served_version.popitem(last=False)
-        t_col = float(self._clock())
+        with self._tracer.annotate("serve.collect"):
+            for s, entries in enumerate(slot_entries):
+                for r, (rid, part) in enumerate(entries):
+                    req = self._reqs[rid]
+                    req.vals[part] = dec[s, r].reshape(t, s_count)
+                    req.left -= 1
+                    if req.left == 0:
+                        out = req.weights[0] * req.vals[0]
+                        for p in range(1, len(req.vals)):
+                            out = out + req.weights[p] * req.vals[p]
+                        results[rid] = out
+                        del self._reqs[rid]
+                        done_ts.append((rid, req.ts))
+                        self.served_version[rid] = version
+                        while len(self.served_version) > _SERVED_VERSION_CAP:
+                            self.served_version.popitem(last=False)
+            t_col = float(self._clock())
         device_ms = (t_dev - t_wait) * 1e3
         collect_ms = (t_col - t_dev) * 1e3
         rec["device_ms"] = device_ms
@@ -778,6 +783,10 @@ class SVMEngine:
         if self.fused:
             # one fused Pallas launch; Gram tiles stay in VMEM
             sv_w = jnp.take(self._sv, cell_idx, axis=0)
+            if self._tracer.enabled:
+                jaxprof.note(sp_ops.svm_predict_cells, xt, sv_w, co_w, ga_w,
+                             kind=self.bank.kernel,
+                             force_pallas=not runtime.on_tpu())
             dec = sp_ops.svm_predict_cells(
                 xt, sv_w, co_w, ga_w, kind=self.bank.kernel,
                 force_pallas=not runtime.on_tpu())
@@ -785,6 +794,8 @@ class SVMEngine:
             return dec
         d2 = self._d2_for(xt, cell_idx)
         self._last_wave = {"xt": xt, "cell_idx": cell_idx, "d2": d2}
+        if self._tracer.enabled:
+            jaxprof.note(_decide_cells, d2, ga_w, co_w, self.bank.kernel)
         return _decide_cells(d2, ga_w, co_w, self.bank.kernel)
 
     # --------------------------------------------------- persistent wave D²

@@ -126,67 +126,69 @@ def save_checkpoint(ckpt_dir: str, step: int, tree: PyTree,
     or torn).
     """
     t_save = time.perf_counter()
-    os.makedirs(ckpt_dir, exist_ok=True)
-    _sweep_stale_tmp(ckpt_dir)
-    paths, leaves, _ = _flatten_with_paths(tree)
-    host = jax.process_index()
+    with obs.tracer.annotate("checkpoint.save"):
+        os.makedirs(ckpt_dir, exist_ok=True)
+        _sweep_stale_tmp(ckpt_dir)
+        paths, leaves, _ = _flatten_with_paths(tree)
+        host = jax.process_index()
 
-    final = os.path.join(ckpt_dir, _step_name(step))
-    tmp = tempfile.mkdtemp(dir=ckpt_dir, prefix=f".tmp_step_{step}_")
-    try:
-        faults.fire("checkpoint.save.pre_shard", step=step)
-        # raw-byte storage: npz cannot roundtrip ml_dtypes (bf16/fp8);
-        # shapes and true dtypes live in the manifest
-        raw = [np.ascontiguousarray(np.asarray(l)).tobytes() for l in leaves]
-        arrays = {f"leaf_{i}": np.frombuffer(b, np.uint8)
-                  for i, b in enumerate(raw)}
-        shard_path = os.path.join(tmp, f"shard_{host}.npz")
-        np.savez(shard_path, **arrays)
-        _fsync_path(shard_path)
-        faults.fire("checkpoint.save.post_shard", step=step)
-        manifest = {
-            "manifest_version": MANIFEST_VERSION,
-            "step": step,
-            "n_leaves": len(leaves),
-            "paths": paths,
-            "shapes": [list(np.shape(l)) for l in leaves],
-            "dtypes": [str(np.asarray(l).dtype) for l in leaves],
-            "checksums": [_leaf_digest(b) for b in raw],
-            "n_processes": jax.process_count(),
-            "extra": extra or {},
-        }
-        man_path = os.path.join(tmp, "manifest.json")
-        with open(man_path, "w") as f:
-            json.dump(manifest, f)
+        final = os.path.join(ckpt_dir, _step_name(step))
+        tmp = tempfile.mkdtemp(dir=ckpt_dir, prefix=f".tmp_step_{step}_")
+        try:
+            faults.fire("checkpoint.save.pre_shard", step=step)
+            # raw-byte storage: npz cannot roundtrip ml_dtypes (bf16/fp8);
+            # shapes and true dtypes live in the manifest
+            raw = [np.ascontiguousarray(np.asarray(l)).tobytes()
+                   for l in leaves]
+            arrays = {f"leaf_{i}": np.frombuffer(b, np.uint8)
+                      for i, b in enumerate(raw)}
+            shard_path = os.path.join(tmp, f"shard_{host}.npz")
+            np.savez(shard_path, **arrays)
+            _fsync_path(shard_path)
+            faults.fire("checkpoint.save.post_shard", step=step)
+            manifest = {
+                "manifest_version": MANIFEST_VERSION,
+                "step": step,
+                "n_leaves": len(leaves),
+                "paths": paths,
+                "shapes": [list(np.shape(l)) for l in leaves],
+                "dtypes": [str(np.asarray(l).dtype) for l in leaves],
+                "checksums": [_leaf_digest(b) for b in raw],
+                "n_processes": jax.process_count(),
+                "extra": extra or {},
+            }
+            man_path = os.path.join(tmp, "manifest.json")
+            with open(man_path, "w") as f:
+                json.dump(manifest, f)
+                f.flush()
+                os.fsync(f.fileno())
+            _fsync_path(tmp)
+            faults.fire("checkpoint.save.pre_rename", step=step)
+            if os.path.exists(final):
+                shutil.rmtree(final)
+            os.rename(tmp, final)
+            _fsync_path(ckpt_dir)
+        except BaseException as e:
+            # an InjectedFault emulates SIGKILL: leave the debris on disk so the
+            # recovery path is tested against what a real kill leaves behind
+            if not isinstance(e, faults.InjectedFault):
+                shutil.rmtree(tmp, ignore_errors=True)
+            raise
+        faults.fire("checkpoint.save.post_rename", step=step)
+
+        # advisory pointer, atomically replaced (a reader never sees a torn
+        # pointer file; a STALE one is handled by the listing fallback)
+        ptr_tmp = os.path.join(ckpt_dir, ".latest.tmp")
+        with open(ptr_tmp, "w") as f:
+            f.write(_step_name(step))
             f.flush()
             os.fsync(f.fileno())
-        _fsync_path(tmp)
-        faults.fire("checkpoint.save.pre_rename", step=step)
-        if os.path.exists(final):
-            shutil.rmtree(final)
-        os.rename(tmp, final)
+        os.replace(ptr_tmp, os.path.join(ckpt_dir, "latest"))
         _fsync_path(ckpt_dir)
-    except BaseException as e:
-        # an InjectedFault emulates SIGKILL: leave the debris on disk so the
-        # recovery path is tested against what a real kill leaves behind
-        if not isinstance(e, faults.InjectedFault):
-            shutil.rmtree(tmp, ignore_errors=True)
-        raise
-    faults.fire("checkpoint.save.post_rename", step=step)
+        faults.fire("checkpoint.save.post_latest", step=step)
 
-    # advisory pointer, atomically replaced (a reader never sees a torn
-    # pointer file; a STALE one is handled by the listing fallback)
-    ptr_tmp = os.path.join(ckpt_dir, ".latest.tmp")
-    with open(ptr_tmp, "w") as f:
-        f.write(_step_name(step))
-        f.flush()
-        os.fsync(f.fileno())
-    os.replace(ptr_tmp, os.path.join(ckpt_dir, "latest"))
-    _fsync_path(ckpt_dir)
-    faults.fire("checkpoint.save.post_latest", step=step)
-
-    _gc(ckpt_dir, keep_last)
-    t_done = time.perf_counter()
+        _gc(ckpt_dir, keep_last)
+        t_done = time.perf_counter()
     obs.tracer.record("checkpoint.save", t_save, t_done)
     obs.metrics.counter("checkpoint.saves").inc()
     if t_done > t_save:
@@ -429,11 +431,12 @@ def _restore_one(ckpt_dir: str, target: PyTree, step: int,
     _RESTORING.add(os.path.abspath(d))
     t_restore = time.perf_counter()
     try:
-        manifest = _read_manifest(d)
-        if manifest is None:
-            raise CheckpointCorruptError(f"{d}: manifest missing or torn")
-        leaves = _read_leaves(d, manifest)
-        t_read = time.perf_counter()
+        with obs.tracer.annotate("checkpoint.restore"):
+            manifest = _read_manifest(d)
+            if manifest is None:
+                raise CheckpointCorruptError(f"{d}: manifest missing or torn")
+            leaves = _read_leaves(d, manifest)
+            t_read = time.perf_counter()
         obs.tracer.record("checkpoint.restore", t_restore, t_read)
         obs.metrics.counter("checkpoint.restores").inc()
         if t_read > t_restore:
