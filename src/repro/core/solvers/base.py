@@ -20,7 +20,16 @@ faithful in-VMEM Gauss-Seidel CD sweep lives in
 ``repro.kernels.cd_solver`` and can be used as a polishing pass.
 
 Stopping: projected-gradient (KKT) residual, uniform across solvers:
-``r = || c - clip(c - g, lo, hi) ||_inf`` with ``g = K c - y``.
+``r = || c - clip(c - g, lo, hi) ||_inf`` with ``g = K c - y``.  It is
+checked once per block of ``check_every`` steps: an outer ``while_loop``
+over blocks, an inner loop of ``check_every`` plain FISTA steps, then one
+check, which costs a second ``K @ C``.  So a solve stops at a multiple of
+``check_every`` or at ``max_iters``.  The check sits outside the step on
+purpose: every train path vmaps the solve over folds (and slots), which
+batches the loop's carry, and a ``lax.cond`` on a batched predicate runs
+both branches, every operand (K too) broadcast to the batch.  A check
+inside the step would cost that second GEMM on every iteration, reading K
+once per fold, instead of once in ``check_every`` iterations.
 """
 from __future__ import annotations
 
@@ -121,12 +130,8 @@ def box_qp(
     def grad(c):
         return _kdot(k_mat, c) - y
 
-    def cond(state):
-        c, z, t, it, res = state
-        return jnp.logical_and(it < max_iters, jnp.max(res) > tol)
-
-    def body(state):
-        c, z, t, it, _ = state
+    def fista_step(_, state):
+        c, z, t = state
         g = grad(z)
         c_new = jnp.clip(z - step * g, lo, hi)
         # gradient-based adaptive restart (O'Donoghue & Candes)
@@ -134,15 +139,27 @@ def box_qp(
         t_new = jnp.where(restart, 1.0, 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t)))
         beta = jnp.where(restart, 0.0, (t - 1.0) / t_new)
         z_new = c_new + beta * (c_new - c)
-        res = jax.lax.cond(
-            (it + 1) % check_every == 0,
-            lambda: kkt_residual(c_new, grad(c_new), lo, hi),
-            lambda: jnp.full((p,), jnp.inf, jnp.float32),
-        )
-        return c_new, z_new, t_new, it + 1, res
+        return c_new, z_new, t_new
 
+    def running(last):
+        return lambda state: jnp.logical_and(state[3] < last, jnp.max(state[4]) > tol)
+
+    def block(state):
+        c, z, t, it, _ = state
+        c, z, t = jax.lax.fori_loop(0, check_every, fista_step, (c, z, t))
+        return c, z, t, it + check_every, kkt_residual(c, grad(c), lo, hi)
+
+    def single(state):
+        c, z, t, it, res = state
+        c, z, t = fista_step(0, (c, z, t))
+        return c, z, t, it + 1, res
+
+    # Whole blocks of check_every steps, each checked once at its end; then
+    # the max_iters % check_every steps left, unchecked, for lanes still running.
+    blocks_end = (max_iters // check_every) * check_every
     init = (c0, c0, jnp.float32(1.0), jnp.int32(0), jnp.full((p,), jnp.inf, jnp.float32))
-    c, _, _, it, _ = jax.lax.while_loop(cond, body, init)
+    state = jax.lax.while_loop(running(blocks_end), block, init)
+    c, _, _, it, _ = jax.lax.while_loop(running(max_iters), single, state)
     final_res = kkt_residual(c, grad(c), lo, hi)
     return BoxQPResult(c=c, kkt=final_res, iters=it, l_est=l_est)
 

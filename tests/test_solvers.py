@@ -78,6 +78,182 @@ class TestBoxQP:
         assert l_est >= 0.99 * l_true  # 1.05 safety factor in estimator
 
 
+# ------------------------------------------- box QP loop: blocks and checks
+
+def _box_qp_cond_loop(k_mat, y, lo, hi, c0=None, tol=1e-3, max_iters=2000,
+                      l_est=None, check_every=10):
+    """The box-QP loop as it was before the check moved to block ends: one
+    ``while_loop`` step per iteration, the KKT check in a ``lax.cond``.  The
+    reference for ``box_qp``'s iterates, counts and residuals."""
+    if k_mat.dtype not in (jnp.bfloat16, jnp.float16):
+        k_mat = k_mat.astype(jnp.float32)
+    if y.ndim == 1:
+        y = y[:, None]
+    p = max(y.shape[1], lo.shape[1] if lo.ndim == 2 else 1, hi.shape[1] if hi.ndim == 2 else 1)
+    n = k_mat.shape[0]
+    y = jnp.broadcast_to(y.astype(jnp.float32), (n, p))
+    lo = jnp.broadcast_to(lo.astype(jnp.float32), (n, p))
+    hi = jnp.broadcast_to(hi.astype(jnp.float32), (n, p))
+    c0 = jnp.zeros((n, p), jnp.float32) if c0 is None else jnp.broadcast_to(c0.astype(jnp.float32), (n, p))
+    c0 = base.clip_warm_start(c0, lo, hi)  # warm starts from a larger box are clipped in
+
+    if l_est is None:
+        l_est = base.power_iteration_l(k_mat)
+    step = 1.0 / l_est
+
+    def grad(c):
+        return base._kdot(k_mat, c) - y
+
+    def cond(state):
+        c, z, t, it, res = state
+        return jnp.logical_and(it < max_iters, jnp.max(res) > tol)
+
+    def body(state):
+        c, z, t, it, _ = state
+        g = grad(z)
+        c_new = jnp.clip(z - step * g, lo, hi)
+        # gradient-based adaptive restart (O'Donoghue & Candes)
+        restart = jnp.sum(g * (c_new - c)) > 0.0
+        t_new = jnp.where(restart, 1.0, 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t)))
+        beta = jnp.where(restart, 0.0, (t - 1.0) / t_new)
+        z_new = c_new + beta * (c_new - c)
+        res = jax.lax.cond(
+            (it + 1) % check_every == 0,
+            lambda: base.kkt_residual(c_new, grad(c_new), lo, hi),
+            lambda: jnp.full((p,), jnp.inf, jnp.float32),
+        )
+        return c_new, z_new, t_new, it + 1, res
+
+    init = (c0, c0, jnp.float32(1.0), jnp.int32(0), jnp.full((p,), jnp.inf, jnp.float32))
+    c, _, _, it, _ = jax.lax.while_loop(cond, body, init)
+    final_res = base.kkt_residual(c, grad(c), lo, hi)
+    return base.BoxQPResult(c=c, kkt=final_res, iters=it, l_est=l_est)
+
+
+def _hinge_folds(gamma, n=48, folds=5, seed=0):
+    """One cell's hinge duals over 4 cost columns, one problem per CV fold:
+    K (n, n) shared, y_eff/lo/hi (folds, n, 4) with held-out rows boxed at 0."""
+    rng = np.random.default_rng(seed)
+    k = _gram(rng.normal(size=(n, 4)), gamma)
+    y = np.sign(rng.normal(size=n)).astype(np.float32)
+    fold = rng.integers(0, folds, size=n)
+    train = np.stack([fold != f for f in range(folds)]).astype(np.float32)[:, :, None]
+    edge = y[None, :, None] * np.asarray([1.0, 10.0, 100.0, 1000.0], np.float32) * train
+    return (k, jnp.asarray(y[None, :, None] * train),
+            jnp.asarray(np.minimum(0.0, edge)), jnp.asarray(np.maximum(0.0, edge)))
+
+
+def _batched_solve(solve, batching, max_iters, tol):
+    """``solve`` on one fold, vmapped over 5 folds, or over 2 slots x 5 folds
+    (slot gammas 1.0 and 1.5: at tol 0.03 the first slot's lanes stop at 20
+    iterations while the second's run on, through any remainder steps)."""
+    def one(k, y, lo, hi):
+        return solve(k, y, lo, hi, tol=tol, max_iters=max_iters)
+    k, y, lo, hi = _hinge_folds(1.5)
+    if batching == "plain":
+        return one(k, y[0], lo[0], hi[0])
+    folds = jax.vmap(one, in_axes=(None, 0, 0, 0))
+    if batching == "folds":
+        return folds(k, y, lo, hi)
+    slots = [_hinge_folds(1.0), (k, y, lo, hi)]
+    return jax.vmap(folds)(*(jnp.stack(a) for a in zip(*slots)))
+
+
+def _loop_bodies(jaxpr):
+    """(body, holds a nested loop) for every while/scan body in jaxpr."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("while", "scan"):
+            body = eqn.params["body_jaxpr" if name == "while" else "jaxpr"].jaxpr
+            inner = list(_loop_bodies(body))
+            yield body, bool(inner)
+            yield from inner
+        else:
+            for sub in _calls(eqn):
+                yield from _loop_bodies(sub)
+
+
+def _calls(eqn):
+    """Sub-jaxprs of a non-loop equation (jit calls, cond branches)."""
+    for v in eqn.params.values():
+        for u in v if isinstance(v, (tuple, list)) else (v,):
+            u = getattr(u, "jaxpr", u)
+            if hasattr(u, "eqns"):
+                yield u
+
+
+def _dots_feeding(jaxpr):
+    """(dot_generals in jaxpr's own body, the most distinct ones feeding
+    any select_n); calls are followed, nested loops are not."""
+    feeds, n_dots, worst = {}, 0, 0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("while", "scan"):
+            continue
+        src = frozenset().union(*(feeds.get(v, frozenset()) for v in eqn.invars
+                                  if not hasattr(v, "val")))
+        for sub in _calls(eqn):
+            sub_dots, sub_worst = _dots_feeding(sub)
+            worst = max(worst, sub_worst)
+            src |= {f"{id(eqn)}.{i}" for i in range(sub_dots)}
+            n_dots += sub_dots
+        if name == "dot_general":
+            n_dots += 1
+            src |= {str(id(eqn))}
+        if name == "select_n":
+            worst = max(worst, len(src))
+        for v in eqn.outvars:
+            feeds[v] = src
+    return n_dots, worst
+
+
+class TestBoxQPBlocks:
+    """``box_qp`` checks the KKT residual once per block of ``check_every``
+    steps, outside the per-iteration body; the result must be the per-step
+    loop's, plain and under vmap, with or without remainder steps."""
+
+    @pytest.mark.parametrize("tol", [0.03, 0.0], ids=["tol_mid", "tol_zero"])
+    @pytest.mark.parametrize("max_iters", [25, 40, 300])
+    @pytest.mark.parametrize("batching", ["plain", "folds", "slots_folds"])
+    def test_matches_cond_loop(self, batching, max_iters, tol):
+        got = _batched_solve(base.box_qp, batching, max_iters, tol)
+        ref = _batched_solve(_box_qp_cond_loop, batching, max_iters, tol)
+        np.testing.assert_array_equal(np.asarray(got.iters), np.asarray(ref.iters))
+        np.testing.assert_allclose(np.asarray(got.c), np.asarray(ref.c), rtol=0, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(got.kkt), np.asarray(ref.kkt), rtol=0, atol=1e-6)
+        if tol == 0.0:
+            assert np.all(np.asarray(got.iters) == max_iters)
+        elif batching == "slots_folds":
+            # some lanes stop at a block end, others run to the cap
+            assert np.unique(np.asarray(got.iters)).size > 1
+
+    def _structure(self, solve, batching):
+        k, y, lo, hi = _hinge_folds(1.5)
+
+        def one(k, y, lo, hi):
+            return solve(k, y, lo, hi, tol=1e-3, max_iters=1000, l_est=jnp.float32(40.0))
+        fn = jax.vmap(one, in_axes=(None, 0, 0, 0))
+        args = (k, y, lo, hi)
+        if batching == "slots_folds":
+            fn, args = jax.vmap(fn), tuple(jnp.stack([a, a]) for a in args)
+        bodies = list(_loop_bodies(jax.make_jaxpr(fn)(*args).jaxpr))
+        return ([_dots_feeding(b) for b, nested in bodies if not nested],
+                [_dots_feeding(b) for b, nested in bodies if nested])
+
+    @pytest.mark.parametrize("batching", ["folds", "slots_folds"])
+    def test_vmapped_step_runs_one_gemm(self, batching):
+        """Under vmap a ``lax.cond`` becomes a select that runs both branches:
+        the per-iteration body must hold the gradient's ``K @ C`` alone, and
+        the check's one per block."""
+        steps, blocks = self._structure(base.box_qp, batching)
+        assert steps and all(n_dots == 1 for n_dots, _ in steps), steps
+        assert all(worst <= 1 for _, worst in steps), steps
+        assert blocks and all(n_dots == 1 for n_dots, _ in blocks), blocks
+        # the guard sees the per-step check of the old loop
+        old_steps, _ = self._structure(_box_qp_cond_loop, batching)
+        assert [(n, w) for n, w in old_steps] == [(2, 2)], old_steps
+
+
 # ------------------------------------------------------- warm-start property
 
 class TestWarmStartProperty:
