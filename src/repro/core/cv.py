@@ -197,15 +197,10 @@ def _solve_columns(k_full, y_cols, train_cols, lam_c, sub_c, n_eff_cols, cfg, c0
             c = cd_ops.cd_polish(k_full, y_eff, lo, hi, c, cfg.cd_polish)
         return c, res.iters
     if cfg.solver == "ls":
-        # all columns must share the fold train mask (task_mask == 1); the
-        # eigh is done once and the lambda path is a diagonal rescale.
-        tm = train_cols[:, 0]
-        km = k_full * tm[:, None] * tm[None, :]
-        s, u = jnp.linalg.eigh(km)
-        s = jnp.maximum(s, 0.0)
-        uty = u.T @ (y_cols * train_cols[:, :1])        # (n, P)
-        denom = s[:, None] + lam_c[None, :] * jnp.maximum(n_eff_cols[None, :], 1.0)
-        return u @ (uty / denom), jnp.int32(0)
+        # all columns must share the fold train mask (task_mask == 1)
+        c = ls_solver.solve_columns(k_full, y_cols, lam_c, n_eff_cols,
+                                    train_cols[:, 0])
+        return c, jnp.int32(0)
     if cfg.solver == "expectile":
         tm = train_cols[:, 0]
         n_eff = n_eff_cols[0]

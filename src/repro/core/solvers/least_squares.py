@@ -3,17 +3,29 @@
 Primal: min_f lambda ||f||^2 + (1/n) sum (y_i - f(x_i))^2.  Stationarity
 gives (K + lambda n I) c = y on the training coordinates.
 
-Beyond-paper optimization (recorded in EXPERIMENTS.md): instead of one
-Cholesky per lambda we eigendecompose the (masked) Gram matrix ONCE per
-(fold, gamma) and sweep the whole lambda path as a diagonal rescale:
+The CV (:func:`solve_columns`) takes one Cholesky per lambda
+(:func:`solve_krr_chol`): XLA's Cholesky is batched over the CV's vmapped
+(slot, fold) lanes, compiles in seconds and costs n^3 / 3 per lambda, and
+in f32 it does not break down at liquidSVM's smallest lambda (lambda n
+~ 1e-4 against ||K|| ~ n).
+
+:func:`solve_krr_eigh` instead eigendecomposes the (masked) Gram matrix
+once and sweeps the whole lambda path as a diagonal rescale:
 
     K = U diag(s) U^T   =>   c(lambda) = U diag(1/(s + lambda n)) U^T y
 
-O(n^3) once + O(n^2) per lambda — the logical endpoint of the paper's
-"kernel matrices may be re-used" for the smooth-loss solver.
+O(n^3) once + O(n^2) per lambda.  The CV does not take it: JAX's TPU eigh
+(QDWH, spectral divide and conquer) maps over every batch axis, so the
+vmapped eighs would run one after another, and one eigh at n = 2853
+compiles for minutes into ~0.9 GB of device code.
 
-Masking: with M = diag(train_mask), eigh(M K M) solves the fold subproblem
-exactly — padded coordinates see (0 + lambda n) c = 0 => c = 0.
+Masking: with M = diag(train_mask), the Cholesky of M K M + lambda n I
+solves the fold subproblem exactly — padded coordinates see
+(0 + lambda n) c = 0 => c = 0, and the factor keeps them apart exactly.
+The eigh path factors M K M + I - M, so that they split off as unit
+eigenvalues: as zero eigenvalues f32 round-off would mix them with the
+training block's smallest, and the path's largest weights (1 / lambda n)
+would leak onto rows that are not trained on.
 """
 from __future__ import annotations
 
@@ -21,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 Array = jax.Array
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _masked(k_mat: Array, train_mask: Array | None) -> Array:
@@ -30,6 +43,23 @@ def _masked(k_mat: Array, train_mask: Array | None) -> Array:
     return k_mat * m[:, None] * m[None, :]
 
 
+def solve_columns(k_mat: Array, y: Array, lambdas: Array, n_eff: Array,
+                  train_mask: Array) -> Array:
+    """The CV's least-squares solve: every column of y (n, P) at its lambda
+    (P,) on one fold's training rows, one :func:`solve_krr_chol` per
+    column.  The columns run one after another (``lax.map``), so a vmapped
+    caller holds one factor per lane, the Gram's own size.  Returns c
+    (n, P), 0 outside ``train_mask``."""
+    m = train_mask.astype(jnp.float32)
+    with jax.named_scope("cv.ls_factor"):
+        km = _masked(k_mat.astype(jnp.float32), m)
+    with jax.named_scope("cv.ls_path"):
+        y = (y.astype(jnp.float32) * m[:, None]).T
+    n_eff = jnp.broadcast_to(n_eff, lambdas.shape)
+    c = jax.lax.map(lambda col: solve_krr_chol(km, *col), (y, lambdas, n_eff))
+    return c.T
+
+
 def solve_krr_eigh(
     k_mat: Array,
     y: Array,
@@ -37,16 +67,21 @@ def solve_krr_eigh(
     n_eff: Array,
     train_mask: Array | None = None,
 ) -> Array:
-    """All-lambda KRR path via one eigh.  Returns c (n, P)."""
+    """All-lambda KRR path via one eigh.  Returns c (n, P), 0 outside
+    ``train_mask``.  Both matmuls run at ``HIGHEST``: on a TPU an f32
+    matmul at the default precision is one bf16 pass."""
     km = _masked(k_mat.astype(jnp.float32), train_mask)
     y = y.astype(jnp.float32)
     if train_mask is not None:
-        y = y * train_mask.astype(jnp.float32)
+        m = train_mask.astype(jnp.float32)
+        km = km + jnp.diag(1.0 - m)
+        y = y * m
     s, u = jnp.linalg.eigh(km)
     s = jnp.maximum(s, 0.0)  # PSD clip against f32 round-off
-    uty = u.T @ y  # (n,)
+    uty = jnp.matmul(u.T, y, precision=HIGHEST)  # (n,)
     denom = s[:, None] + lambdas[None, :].astype(jnp.float32) * jnp.maximum(n_eff, 1.0)  # (n, P)
-    return u @ (uty[:, None] / denom)
+    c = jnp.matmul(u, uty[:, None] / denom, precision=HIGHEST)
+    return c if train_mask is None else c * m[:, None]
 
 
 def solve_krr_chol(
@@ -56,12 +91,19 @@ def solve_krr_chol(
     n_eff: Array,
     train_mask: Array | None = None,
 ) -> Array:
-    """Single-lambda Cholesky path (used by IRLS and small problems)."""
+    """Single-lambda Cholesky path; the CV runs it once per column
+    (:func:`solve_columns`).  Reads the lower triangle of the symmetric
+    ``k_mat``.  XLA's Cholesky and triangular solves compute their
+    products at ``HIGHEST``."""
     km = _masked(k_mat.astype(jnp.float32), train_mask)
     y = y.astype(jnp.float32)
     if train_mask is not None:
         y = y * train_mask.astype(jnp.float32)
     n = km.shape[0]
-    a = km + (lam * jnp.maximum(n_eff, 1.0)) * jnp.eye(n, dtype=jnp.float32)
-    cf = jax.scipy.linalg.cho_factor(a)
-    return jax.scipy.linalg.cho_solve(cf, y)
+    with jax.named_scope("cv.ls_factor"):
+        a = km + (lam * jnp.maximum(n_eff, 1.0)) * jnp.eye(n, dtype=jnp.float32)
+        # K is symmetric: (A + A^T) / 2 would cost a transposed copy of
+        # the fold matrix, ~10 % of an ls wave's device time on a v5e
+        low = jax.lax.linalg.cholesky(a, symmetrize_input=False)
+    with jax.named_scope("cv.ls_path"):
+        return jax.scipy.linalg.cho_solve((low, True), y)

@@ -110,6 +110,17 @@ def fista_counts(iters: np.ndarray, mask: np.ndarray, max_iters: int,
             "capped": int((mine >= max_iters).sum()), "lane_iters": lane}
 
 
+def ls_counts(mask: np.ndarray, n_gamma: int, n_folds: int) -> dict:
+    """One wave's least-squares counts: ``paths`` is the lambda paths the
+    real slots solved, one per (slot, gamma, fold): every lambda of one
+    fold's training block at one gamma, however it is factorised;
+    ``lane_paths`` is what the device ran, padding slots included."""
+    real = np.asarray(mask).reshape(mask.shape[0], -1).sum(axis=1) > 0
+    per_slot = n_gamma * n_folds
+    return {"paths": int(real.sum()) * per_slot,
+            "lane_paths": int(real.size) * per_slot}
+
+
 _WAVE_KEYS = ("coefs", "gamma", "lam", "tau", "val")
 _SURFACE_KEYS = ("surf_loss", "surf_fa", "surf_det")
 
@@ -176,6 +187,8 @@ def train_cells_waves(
     box_qp = cfg.solver in ("hinge", "quantile")
     m_fista = {k: obs.metrics.counter("train.fista." + k)
                for k in ("solves", "iters", "capped", "lane_iters")}
+    m_ls = {k: obs.metrics.counter("train.ls." + k)
+            for k in ("paths", "lane_paths")}
 
     keys_out = wave_keys(cfg)
     if wave_size is None or wave_size >= n_slots:
@@ -241,6 +254,12 @@ def train_cells_waves(
                     for k, v in counts.items():
                         m_fista[k].inc(v)
                     sp.set(**{"fista_" + k: v for k, v in counts.items()})
+                elif cfg.solver == "ls":
+                    counts = ls_counts(arrays[3], arrays[4].shape[1],
+                                       cfg.n_folds)
+                    for k, v in counts.items():
+                        m_ls[k].inc(v)
+                    sp.set(**{"ls_" + k: v for k, v in counts.items()})
             m_solved.inc()
             faults.fire("trainer.wave.solved", wave=w)
             if ckpt_dir is not None:

@@ -35,8 +35,11 @@ import jax
 import numpy as np
 
 # the program's named scopes (``jax.named_scope`` in ``core/cv.cv_cell``):
-# the Gram's distance matrix, the per-gamma kernel epilogue, the solve
-SCOPES: Tuple[str, ...] = ("cv.d2", "cv.epilogue", "cv.solve")
+# the Gram's distance matrix, the per-gamma kernel epilogue, the solve, and
+# inside the least-squares solve (``core/solvers/least_squares``) its
+# factorisation and its solves for the lambda path
+SCOPES: Tuple[str, ...] = ("cv.d2", "cv.epilogue", "cv.solve",
+                           "cv.ls_factor", "cv.ls_path")
 
 # process-global profile directory; None = no capture
 _PROFILE_DIR: Optional[str] = None

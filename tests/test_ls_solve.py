@@ -1,0 +1,196 @@
+"""The least-squares (lsSVM) solve of the CV against a float64 reference.
+
+What is pinned here and why:
+  * every matmul of the ls solve runs at ``HIGHEST``: on a TPU an f32
+    matmul at the default precision is one bf16 pass.  The CV's branch
+    solves by Cholesky and triangular solves, whose products XLA computes
+    at ``HIGHEST``; ``solve_krr_eigh``'s two matmuls say so themselves;
+  * ``train_cells_waves`` with ``solver="ls"`` over three small cells of the
+    benchmark's YearPredictionMSD-shaped rows (d = 90, 5 folds, the 10x10
+    grid) selects the same (gamma, lambda), and gives the same validation
+    surface and fold-averaged model, as a plain float64 numpy kernel-ridge
+    CV;
+  * the model is exactly 0 off each fold's training rows at every lambda,
+    the smallest included, on the CV's solve and on ``solve_krr_eigh``
+    (whose masked rows must not mix with the training block's smallest
+    eigenvalues).
+"""
+import importlib.util
+import os
+
+import jax
+import jax.extend.core
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core import cv as cv_mod
+from repro.core import grids, kernel_fns
+from repro.core.solvers import least_squares as ls
+from repro.distributed import cell_trainer
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "bench")
+K, D, FOLDS = 96, 90, 5
+SIZES = (96, 90, 81)                 # ragged real rows, padded to K
+
+
+def _data_reg():
+    spec = importlib.util.spec_from_file_location(
+        "bench_data_reg", os.path.join(BENCH, "data_reg.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (map, jit) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from _eqns(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("solve", ["cv_branch", "solve_krr_eigh"])
+def test_ls_branch_matmuls_run_at_highest(solve):
+    n, p = 16, 4
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(n, 3)), jnp.float32)
+    k_full = kernel_fns.get_spec("gauss_rbf").fn(x, x, jnp.float32(1.5))
+    y_cols = jnp.asarray(rng.normal(size=(n, p)), jnp.float32)
+    train = jnp.asarray(rng.uniform(size=(n, 1)) < 0.7, jnp.float32) \
+        * jnp.ones((1, p))
+    lam_c = jnp.asarray([1.0, 0.1, 0.01, 0.001], jnp.float32)
+    if solve == "cv_branch":
+        cfg = cv_mod.CVConfig(solver="ls")
+        jaxpr = jax.make_jaxpr(
+            lambda k, y, t: cv_mod._solve_columns(
+                k, y, t, lam_c, jnp.ones(p), jnp.sum(t, axis=0), cfg, None,
+                None)[0])(k_full, y_cols, train)
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda k, y, t: ls.solve_krr_eigh(k, y, lam_c, jnp.sum(t),
+                                              t))(k_full, y_cols[:, 0],
+                                                  train[:, 0])
+    eqns = list(_eqns(jaxpr.jaxpr))
+    names = {e.primitive.name for e in eqns}
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    if solve == "cv_branch":
+        assert {"cholesky", "triangular_solve"} <= names
+        assert "eigh" not in names
+    else:
+        assert len(dots) == 2               # U^T y and U (U^T y / denom)
+    hi = jax.lax.Precision.HIGHEST
+    assert all(e.params["precision"] == (hi, hi) for e in dots), [
+        e.params["precision"] for e in dots]
+
+
+def _cells(seed=0):
+    """Three cells of year_like rows, standardised by their own statistics,
+    targets centred on their mean year; padding rows are 0."""
+    x_all, year = _data_reg().year_like(n=4000, d=D, seed=seed)
+    x_all = (x_all - x_all.mean(0)) / x_all.std(0)
+    y_all = year - year.mean()
+    x = np.zeros((len(SIZES), K, D), np.float32)
+    y = np.zeros((len(SIZES), 1, K), np.float32)
+    m = np.zeros((len(SIZES), K), np.float32)
+    lo = 0
+    for s, size in enumerate(SIZES):
+        x[s, :size] = x_all[lo:lo + size]
+        y[s, 0, :size] = y_all[lo:lo + size]
+        m[s, :size] = 1.0
+        lo += size
+    gam = np.stack([np.asarray(grids.liquid_grid(
+        n=size, dim=D, median_dist=float(kernel_fns.median_heuristic(
+            jnp.asarray(x[s]), jnp.asarray(m[s]))), grid_choice=0,
+        cell_size=2000).gammas, np.float32) for s, size in enumerate(SIZES)])
+    keys = np.asarray(jax.random.split(jax.random.PRNGKey(seed), len(SIZES)))
+    return x, y, m, gam, keys
+
+
+def _krr_cv64(x, y, m, gammas, lambdas, val):
+    """Plain float64 k-fold CV of kernel ridge regression on one cell: one
+    dense solve per (fold, gamma, lambda), validation MSE, fold-averaged
+    coefficients, and the argmin (first least lambda per gamma, then
+    strictly better gammas)."""
+    x, y = x.astype(np.float64), y.astype(np.float64)
+    sq = (x * x).sum(1)
+    d2 = np.maximum(sq[:, None] + sq[None, :] - 2.0 * x @ x.T, 0.0)
+    real = m > 0
+    surface = np.zeros((len(gammas), len(lambdas)))
+    coefs = np.zeros((len(gammas), len(x), len(lambdas)))
+    for g, gamma in enumerate(np.asarray(gammas, np.float64)):
+        kg = np.exp(-d2 / gamma ** 2)
+        for va in val:
+            tr = ~va & real
+            k_tt, k_vt = kg[np.ix_(tr, tr)], kg[np.ix_(va, tr)]
+            for j, lam in enumerate(np.asarray(lambdas, np.float64)):
+                c = np.linalg.solve(k_tt + lam * tr.sum() * np.eye(tr.sum()),
+                                    y[tr])
+                surface[g, j] += np.mean((y[va] - k_vt @ c) ** 2) / len(val)
+                coefs[g, tr, j] += c / len(val)
+    best, gi, li = np.inf, 0, 0
+    for g in range(len(gammas)):
+        j = int(np.argmin(surface[g]))
+        if surface[g, j] < best:
+            best, gi, li = surface[g, j], g, j
+    return surface, coefs, gi, li
+
+
+def test_train_cells_waves_ls_matches_float64_krr_cv():
+    x, y, m, gam, keys = _cells()
+    cfg = cv_mod.CVConfig(solver="ls", n_folds=FOLDS, keep_surface=True)
+    base = grids.liquid_grid(n=K, dim=D, median_dist=1.0, grid_choice=0,
+                             cell_size=2000)
+    lam_c, sub_c, task_c, n_lam, n_sub = cv_mod.grid_columns(base, cfg, 1)
+
+    def stage(lo, hi):
+        return x[lo:hi], y[lo:hi], y[lo:hi] * 0 + m[lo:hi, None], m[lo:hi], \
+            gam[lo:hi], keys[lo:hi]
+
+    coefs, gamma, lam, _, _, surf = cell_trainer.train_cells_waves(
+        stage, len(SIZES), 2, lam_c, sub_c, task_c, cfg, n_lam, n_sub)[:6]
+    lambdas = np.asarray(base.lambdas)
+    for s in range(len(SIZES)):
+        val = np.asarray(cv_mod.make_fold_masks(jnp.asarray(keys[s]),
+                                                jnp.asarray(m[s]), FOLDS))
+        ref_s, ref_c, gi, li = _krr_cv64(x[s], y[s, 0], m[s], gam[s],
+                                         lambdas, val)
+        # the same grid point: these cells' two least surface entries are
+        # apart by 4e-4 relative or more, the f32 gap near them less
+        assert gamma[s, 0, 0] == gam[s, gi] and lam[s, 0, 0] == lambdas[li]
+        # validation MSE over all 100 points, lambda_min included: an f32
+        # Gram and Cholesky against float64, whose round-off (~eps ||K||
+        # ~ 1e-5 against lambda n down to ~1e-4) reads up to 4.4e-5 off
+        # on these cells; ~10x room
+        np.testing.assert_allclose(surf[s, :, 0, :, 0], ref_s, rtol=5e-4)
+        # the fold-averaged model at the selected point, which here lies
+        # near lambda_min: the same round-off, 1.6e-5 at most; ~10x room
+        c_ref = ref_c[gi, :, li]
+        assert np.max(np.abs(coefs[s, :, 0, 0] - c_ref)) \
+            <= 2e-4 * np.max(np.abs(c_ref))
+
+
+@pytest.mark.parametrize("solve", ["solve_columns", "solve_krr_eigh"])
+def test_ls_model_is_zero_off_the_training_rows(solve):
+    x, y, m, gam, keys = _cells(seed=1)
+    k_full = kernel_fns.get_spec("gauss_rbf").fn(
+        jnp.asarray(x[0]), jnp.asarray(x[0]), jnp.float32(gam[0, 0]))
+    train = (np.arange(K) % 5 != 0) & (m[0] > 0)
+    lams = jnp.asarray(grids.liquid_grid(n=K, dim=D, median_dist=1.0,
+                                         grid_choice=0).lambdas)
+    t = jnp.asarray(train, jnp.float32)
+    if solve == "solve_columns":
+        c = ls.solve_columns(
+            k_full, jnp.asarray(np.repeat(y[0, 0][:, None], len(lams), 1)),
+            lams, jnp.float32(train.sum()), t)
+    else:
+        c = ls.solve_krr_eigh(k_full, jnp.asarray(y[0, 0]), lams,
+                              jnp.float32(train.sum()), t)
+    c = np.asarray(c)
+    assert np.all(c[~train] == 0.0)
+    assert np.all(np.isfinite(c))
