@@ -135,6 +135,25 @@ def make_fold_masks(
     return jax.nn.one_hot(fold_of, n_folds, axis=0, dtype=jnp.bool_)
 
 
+def fold_train_bound(n: int, n_folds: int) -> int:
+    """The most training rows a fold of an n-row padded cell can hold:
+    every scheme of :func:`make_fold_masks` puts at least
+    ``n_real // n_folds`` of a cell's ``n_real <= n`` real rows in each
+    fold's validation part, so a fold trains on at most
+    ``n - n // n_folds``."""
+    return n if n_folds < 2 else n - n // n_folds
+
+
+def ls_factor_side(n: int, n_folds: int) -> int:
+    """Side of the block the least-squares solve factors per fold:
+    :func:`fold_train_bound` rounded up to a multiple of 128, at most n.
+    XLA's TPU Cholesky works in panels of 128 columns, and a side of whole
+    panels leaves no ragged last panel to pad (n = 2853, 5 folds: 2304
+    rather than 2283; the 2-slot wave compiles with four pads fewer and
+    runs 0.6 % faster on a v5e)."""
+    return min(n, -(-fold_train_bound(n, n_folds) // 128) * 128)
+
+
 def grid_columns(grid: GridSpec, cfg: CVConfig, n_tasks: int):
     """Task-major flattened columns.  Returns dict of (P,) arrays + ids."""
     lam = grid.lambdas.astype(jnp.float32)
@@ -198,8 +217,9 @@ def _solve_columns(k_full, y_cols, train_cols, lam_c, sub_c, n_eff_cols, cfg, c0
         return c, res.iters
     if cfg.solver == "ls":
         # all columns must share the fold train mask (task_mask == 1)
-        c = ls_solver.solve_columns(k_full, y_cols, lam_c, n_eff_cols,
-                                    train_cols[:, 0])
+        c = ls_solver.solve_columns(
+            k_full, y_cols, lam_c, n_eff_cols, train_cols[:, 0],
+            ls_factor_side(k_full.shape[0], cfg.n_folds))
         return c, jnp.int32(0)
     if cfg.solver == "expectile":
         tm = train_cols[:, 0]

@@ -19,6 +19,19 @@ O(n^3) once + O(n^2) per lambda.  The CV does not take it: JAX's TPU eigh
 vmapped eighs would run one after another, and one eigh at n = 2853
 compiles for minutes into ~0.9 GB of device code.
 
+Compaction: a fold's validation rows and the cell's padding rows carry
+no part of its system, so :func:`solve_columns` gathers the fold's
+training rows (in their order) into a block of side n_c before the
+lambda loop and scatters c back after it: the Cholesky costs n_c^3 / 3
+and its triangular solves n_c^2, not n^3 / 3 and n^2.  The block is a
+static shape, so n_c must hold the most training rows any fold can have:
+the CV passes ``cv.ls_factor_side(n, n_folds)``, which is
+``cv.fold_train_bound`` = n - n // n_folds (every scheme of
+``cv.make_fold_masks`` puts at least n_real // n_folds of a cell's
+n_real <= n real rows in each fold's validation part) rounded up to whole
+128-column panels (n = 2853, 5 folds: 2304).  Padding rows vary from cell
+to cell and stay in the block, masked, as do the rows the rounding adds.
+
 Masking: with M = diag(train_mask), the Cholesky of M K M + lambda n I
 solves the fold subproblem exactly — padded coordinates see
 (0 + lambda n) c = 0 => c = 0, and the factor keeps them apart exactly.
@@ -44,20 +57,27 @@ def _masked(k_mat: Array, train_mask: Array | None) -> Array:
 
 
 def solve_columns(k_mat: Array, y: Array, lambdas: Array, n_eff: Array,
-                  train_mask: Array) -> Array:
+                  train_mask: Array, n_c: int) -> Array:
     """The CV's least-squares solve: every column of y (n, P) at its lambda
     (P,) on one fold's training rows, one :func:`solve_krr_chol` per
-    column.  The columns run one after another (``lax.map``), so a vmapped
-    caller holds one factor per lane, the Gram's own size.  Returns c
-    (n, P), 0 outside ``train_mask``."""
-    m = train_mask.astype(jnp.float32)
+    column.  The training rows are gathered first (in their order) into an
+    ``n_c``-row block, which must hold them all; the factor and its path
+    see only that block, and the block's other rows stay masked.  The
+    columns run one after another (``lax.map``), so a vmapped caller holds
+    one ``n_c x n_c`` factor per lane.  Returns c (n, P), 0 outside
+    ``train_mask``."""
     with jax.named_scope("cv.ls_factor"):
-        km = _masked(k_mat.astype(jnp.float32), m)
+        rows = jnp.argsort((train_mask == 0).astype(jnp.int32),
+                           stable=True)[:n_c]
+        m = train_mask[rows].astype(jnp.float32)
+        km = _masked(k_mat[rows][:, rows].astype(jnp.float32), m)
     with jax.named_scope("cv.ls_path"):
-        y = (y.astype(jnp.float32) * m[:, None]).T
+        y = (y[rows].astype(jnp.float32) * m[:, None]).T
     n_eff = jnp.broadcast_to(n_eff, lambdas.shape)
     c = jax.lax.map(lambda col: solve_krr_chol(km, *col), (y, lambdas, n_eff))
-    return c.T
+    with jax.named_scope("cv.ls_path"):
+        return jnp.zeros((k_mat.shape[0], c.shape[0]), jnp.float32) \
+            .at[rows].set(c.T, unique_indices=True)
 
 
 def solve_krr_eigh(

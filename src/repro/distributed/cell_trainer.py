@@ -114,11 +114,20 @@ def ls_counts(mask: np.ndarray, n_gamma: int, n_folds: int) -> dict:
     """One wave's least-squares counts: ``paths`` is the lambda paths the
     real slots solved, one per (slot, gamma, fold): every lambda of one
     fold's training block at one gamma, however it is factorised;
-    ``lane_paths`` is what the device ran, padding slots included."""
-    real = np.asarray(mask).reshape(mask.shape[0], -1).sum(axis=1) > 0
+    ``lane_paths`` is what the device ran, padding slots included.
+    ``factor_rows`` is the side of the block each lane path factors
+    (``cv.ls_factor_side``) times ``lane_paths``; ``train_rows`` the real
+    training rows in them, ``(n_folds - 1)`` times each real row per gamma
+    (every real row validates in exactly one fold).  Their ratio is the
+    factor's fill."""
+    rows = np.asarray(mask).reshape(mask.shape[0], -1) > 0
+    real = rows.any(axis=1)
     per_slot = n_gamma * n_folds
-    return {"paths": int(real.sum()) * per_slot,
-            "lane_paths": int(real.size) * per_slot}
+    lane_paths = int(real.size) * per_slot
+    side = cv_mod.ls_factor_side(rows.shape[1], n_folds)
+    return {"paths": int(real.sum()) * per_slot, "lane_paths": lane_paths,
+            "factor_rows": side * lane_paths,
+            "train_rows": (n_folds - 1) * int(rows.sum()) * n_gamma}
 
 
 _WAVE_KEYS = ("coefs", "gamma", "lam", "tau", "val")
@@ -188,7 +197,7 @@ def train_cells_waves(
     m_fista = {k: obs.metrics.counter("train.fista." + k)
                for k in ("solves", "iters", "capped", "lane_iters")}
     m_ls = {k: obs.metrics.counter("train.ls." + k)
-            for k in ("paths", "lane_paths")}
+            for k in ("paths", "lane_paths", "factor_rows", "train_rows")}
 
     keys_out = wave_keys(cfg)
     if wave_size is None or wave_size >= n_slots:

@@ -83,6 +83,14 @@ WELL_KNOWN: Dict[str, str] = {
                               "batched loop ran: per device and gamma, the "
                               "slowest lane's count times the lanes it "
                               "carried (padding included)",
+    "train.ls.paths": "counter: least-squares lambda paths of real "
+                      "(non-padding) slots, one per slot, gamma and fold",
+    "train.ls.lane_paths": "counter: lambda paths the wave ran, padding "
+                           "slots included",
+    "train.ls.factor_rows": "counter: side of the block each lane path "
+                            "factors, times lane_paths",
+    "train.ls.train_rows": "counter: real fold training rows of those "
+                           "paths (the factor's fill over factor_rows)",
     "select.columns_resolved": "counter: select-stage targeted re-solves",
     "checkpoint.saves": "counter: checkpoint steps written",
     "checkpoint.restores": "counter: checkpoint steps restored",

@@ -6,7 +6,9 @@ What is pinned here and why:
     the ``ls.*`` metrics read those scopes;
   * ``train.ls.paths`` counts the real slots' lambda paths (slot x gamma x
     fold) and ``train.ls.lane_paths`` what the device ran, padding slots
-    included; both ride on the ``train.wave.solve`` span;
+    included; ``train.ls.factor_rows`` the factored block's side times the
+    lane paths, ``train.ls.train_rows`` the real fold training rows in
+    them; all ride on the ``train.wave.solve`` span;
   * the two solvers' counters do not cross: an ls wave moves no
     ``train.fista.*`` counter, a hinge wave no ``train.ls.*`` one.
 """
@@ -25,7 +27,7 @@ K, D, FOLDS = 32, 3, 2
 GAMMAS = (0.6, 1.1, 2.3)
 LAMBDAS = (0.05, 0.005)
 FISTA = ("solves", "iters", "capped", "lane_iters")
-LS = ("paths", "lane_paths")
+LS = ("paths", "lane_paths", "factor_rows", "train_rows")
 
 
 def _cfg(solver):
@@ -114,14 +116,17 @@ def _scoped_eqns(jaxpr, stack=""):
 
 
 def test_ls_square_work_is_scoped():
-    """Every op of the CV's ls branch on an n x n array, the fold's masked
-    Gram included, lies in ``cv.ls_factor`` or ``cv.ls_path``: work left
-    outside them would be missing from ``ls.ms_per_path``."""
+    """Every op of the CV's ls branch on an array of the factored block's
+    size or more (the gathers and the fold's masked Gram included) lies in
+    ``cv.ls_factor`` or ``cv.ls_path``: work left outside them would be
+    missing from ``ls.ms_per_path``."""
     n, p = 16, 4
+    n_c = cv_mod.ls_factor_side(n, FOLDS)
     rng = np.random.default_rng(0)
     k_full = jnp.asarray(rng.normal(size=(n, n)), jnp.float32)
     y_cols = jnp.asarray(rng.normal(size=(n, p)), jnp.float32)
-    train = jnp.ones((n, p), jnp.float32)
+    train = (jnp.arange(n) < n_c).astype(jnp.float32)[:, None] \
+        * jnp.ones((1, p))
     lam_c = jnp.asarray([1.0, 0.1, 0.01, 0.001], jnp.float32)
     jaxpr = jax.make_jaxpr(
         lambda k, y, t: cv_mod._solve_columns(
@@ -129,7 +134,7 @@ def test_ls_square_work_is_scoped():
             None, None)[0])(k_full, y_cols, train)
     square = [(e.primitive.name, stack)
               for e, stack in _scoped_eqns(jaxpr.jaxpr)
-              if any(np.prod(v.aval.shape) >= n * n for v in e.outvars)]
+              if any(np.prod(v.aval.shape) >= n_c * n_c for v in e.outvars)]
     assert {"cholesky", "mul"} <= {name for name, _ in square}
     assert all("cv.ls_factor" in stack or "cv.ls_path" in stack
                for _, stack in square), square
@@ -143,17 +148,30 @@ def test_hinge_compile_has_no_ls_scope():
 
 
 def test_ls_counts_real_and_lane_paths():
-    mask = np.ones((3, 4))
+    mask = np.ones((3, 10))
+    mask[1, 7:] = 0.0                                       # 7 real rows
     mask[2] = 0.0                                           # padding slot
+    side = cv_mod.ls_factor_side(10, 5)                     # 8
     assert cell_trainer.ls_counts(mask, n_gamma=10, n_folds=5) == {
-        "paths": 2 * 10 * 5, "lane_paths": 3 * 10 * 5}
+        "paths": 2 * 10 * 5, "lane_paths": 3 * 10 * 5,
+        "factor_rows": side * 3 * 10 * 5,
+        "train_rows": 4 * (10 + 7) * 10}
 
 
 def test_ls_wave_counters_and_span():
-    moved, spans = _run_waves(_slots(4, 3, "ls"), wave=2, cfg=_cfg("ls"))
+    arrays = _slots(4, 3, "ls")
+    moved, spans = _run_waves(arrays, wave=2, cfg=_cfg("ls"))
     per_slot = len(GAMMAS) * FOLDS
     assert moved["train.ls.paths"] == 3 * per_slot          # padding adds none
     assert moved["train.ls.lane_paths"] == 4 * per_slot
+    # one validation fold per real row: it trains in FOLDS - 1 of them
+    real_rows = int(arrays[3].sum())                        # K + K-3 + K-6
+    assert moved["train.ls.train_rows"] \
+        == (FOLDS - 1) * real_rows * len(GAMMAS)
+    assert moved["train.ls.factor_rows"] \
+        == cv_mod.ls_factor_side(K, FOLDS) * 4 * per_slot
+    for k in ("factor_rows", "train_rows"):
+        assert sum(s.attrs["ls_" + k] for s in spans) == moved["train.ls." + k]
     assert all(moved["train.fista." + k] == 0 for k in FISTA)
     assert len(spans) == 2
     assert sum(s.attrs["ls_paths"] for s in spans) == 3 * per_slot

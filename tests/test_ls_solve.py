@@ -13,7 +13,13 @@ What is pinned here and why:
   * the model is exactly 0 off each fold's training rows at every lambda,
     the smallest included, on the CV's solve and on ``solve_krr_eigh``
     (whose masked rows must not mix with the training block's smallest
-    eigenvalues).
+    eigenvalues);
+  * the CV's solve factors a block of ``cv.ls_factor_side(n, folds)``
+    rows, not the padded cell: no fold of ``make_fold_masks`` trains on
+    more than ``cv.fold_train_bound`` rows (and some fold on exactly that
+    many), the compacted solve agrees with the masked solve of the whole
+    cell to f32 rounding, and every Cholesky of the ls wave is of that
+    side, while the hinge wave does not depend on the ls solve at all.
 """
 import importlib.util
 import os
@@ -33,6 +39,9 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "bench")
 K, D, FOLDS = 96, 90, 5
 SIZES = (96, 90, 81)                 # ragged real rows, padded to K
+# cells wide enough that the factored block is narrower than the cell:
+# ls_factor_side(300, 5) = 256
+BIG_K, BIG_SIZES = 300, (300, 262, 229)
 
 
 def _data_reg():
@@ -89,17 +98,17 @@ def test_ls_branch_matmuls_run_at_highest(solve):
         e.params["precision"] for e in dots]
 
 
-def _cells(seed=0):
+def _cells(seed=0, k=K, sizes=SIZES):
     """Three cells of year_like rows, standardised by their own statistics,
     targets centred on their mean year; padding rows are 0."""
     x_all, year = _data_reg().year_like(n=4000, d=D, seed=seed)
     x_all = (x_all - x_all.mean(0)) / x_all.std(0)
     y_all = year - year.mean()
-    x = np.zeros((len(SIZES), K, D), np.float32)
-    y = np.zeros((len(SIZES), 1, K), np.float32)
-    m = np.zeros((len(SIZES), K), np.float32)
+    x = np.zeros((len(sizes), k, D), np.float32)
+    y = np.zeros((len(sizes), 1, k), np.float32)
+    m = np.zeros((len(sizes), k), np.float32)
     lo = 0
-    for s, size in enumerate(SIZES):
+    for s, size in enumerate(sizes):
         x[s, :size] = x_all[lo:lo + size]
         y[s, 0, :size] = y_all[lo:lo + size]
         m[s, :size] = 1.0
@@ -107,8 +116,8 @@ def _cells(seed=0):
     gam = np.stack([np.asarray(grids.liquid_grid(
         n=size, dim=D, median_dist=float(kernel_fns.median_heuristic(
             jnp.asarray(x[s]), jnp.asarray(m[s]))), grid_choice=0,
-        cell_size=2000).gammas, np.float32) for s, size in enumerate(SIZES)])
-    keys = np.asarray(jax.random.split(jax.random.PRNGKey(seed), len(SIZES)))
+        cell_size=2000).gammas, np.float32) for s, size in enumerate(sizes)])
+    keys = np.asarray(jax.random.split(jax.random.PRNGKey(seed), len(sizes)))
     return x, y, m, gam, keys
 
 
@@ -187,10 +196,132 @@ def test_ls_model_is_zero_off_the_training_rows(solve):
     if solve == "solve_columns":
         c = ls.solve_columns(
             k_full, jnp.asarray(np.repeat(y[0, 0][:, None], len(lams), 1)),
-            lams, jnp.float32(train.sum()), t)
+            lams, jnp.float32(train.sum()), t, cv_mod.ls_factor_side(K, 5))
     else:
         c = ls.solve_krr_eigh(k_full, jnp.asarray(y[0, 0]), lams,
                               jnp.float32(train.sum()), t)
     c = np.asarray(c)
     assert np.all(c[~train] == 0.0)
     assert np.all(np.isfinite(c))
+
+
+@pytest.mark.parametrize("n_folds", [2, 5])
+@pytest.mark.parametrize("scheme", ["random", "stratified", "blocks"])
+def test_fold_training_rows_fit_the_factored_block(scheme, n_folds):
+    """Every fold of a cell with 1 to n real rows trains on at most
+    ``fold_train_bound(n, n_folds)`` rows, and some fold on exactly that
+    many: a bound one smaller would drop a training row."""
+    n = 97
+    n_real = np.arange(1, n + 1)
+    masks = jnp.asarray(np.arange(n)[None, :] < n_real[:, None], jnp.float32)
+    labels = np.where(np.random.default_rng(4).uniform(size=n) < 0.3, 1., -1.)
+    keys = jax.random.split(jax.random.PRNGKey(3), n)
+    val = jax.vmap(lambda key, m: cv_mod.make_fold_masks(
+        key, m, n_folds, scheme, jnp.asarray(labels, jnp.float32)))(keys,
+                                                                    masks)
+    train = np.sum(~np.asarray(val) & (np.asarray(masks) > 0)[:, None, :],
+                   axis=-1)                                  # (n_real, folds)
+    assert train.max() == cv_mod.fold_train_bound(n, n_folds)
+
+
+def _masked_full_solve(k_mat, y, lambdas, n_eff, train_mask):
+    """The uncompacted CV solve: the whole padded cell masked to the
+    fold's training rows (``K m m^T + lambda n I``), factored at full
+    side, one Cholesky per lambda."""
+    m = train_mask.astype(jnp.float32)
+    with jax.named_scope("cv.ls_factor"):
+        km = ls._masked(k_mat.astype(jnp.float32), m)
+    with jax.named_scope("cv.ls_path"):
+        y = (y.astype(jnp.float32) * m[:, None]).T
+    n_eff = jnp.broadcast_to(n_eff, lambdas.shape)
+    c = jax.lax.map(lambda col: ls.solve_krr_chol(km, *col),
+                    (y, lambdas, n_eff))
+    return c.T
+
+
+@pytest.mark.parametrize("scheme", ["random", "stratified", "blocks"])
+def test_compacted_solve_matches_the_masked_full_solve(scheme):
+    """Each fold of three padded cells, at the smallest, a middle and the
+    largest gamma, lambda from 1 down to 1e-5: the compacted solve and the
+    masked full-side one solve the same system in f32, so per column they
+    differ by no more than f32 rounding through that system: 16 eps (about
+    sqrt(n) of the block's rows) times its condition number (float64
+    eigenvalues of the fold block) times the column's scale.  Off the
+    fold's training rows the model is exactly 0."""
+    x, y, m, gam, keys = _cells(seed=2, k=BIG_K, sizes=BIG_SIZES)
+    lams = np.geomspace(1.0, 1e-5, 6)
+    side = cv_mod.ls_factor_side(BIG_K, FOLDS)
+    eps = np.finfo(np.float32).eps
+    solves = [jax.jit(jax.vmap(solve, in_axes=(None, None, 0))) for solve in (
+        lambda k, yc, t: ls.solve_columns(
+            k, yc, jnp.asarray(lams, jnp.float32), jnp.sum(t), t, side),
+        lambda k, yc, t: _masked_full_solve(
+            k, yc, jnp.asarray(lams, jnp.float32), jnp.sum(t), t))]
+    for s in range(len(BIG_SIZES)):
+        val = np.asarray(cv_mod.make_fold_masks(
+            jnp.asarray(keys[s]), jnp.asarray(m[s]), FOLDS, scheme,
+            jnp.asarray(y[s, 0])))
+        train = ~val & (m[s] > 0)[None, :]                  # (folds, k)
+        y_cols = jnp.asarray(np.repeat(y[s, 0][:, None], len(lams), 1))
+        for g in (0, 5, 9):
+            k_full = kernel_fns.get_spec("gauss_rbf").fn(
+                jnp.asarray(x[s]), jnp.asarray(x[s]), jnp.float32(gam[s, g]))
+            new, old = (np.asarray(f(k_full, y_cols,
+                                     jnp.asarray(train, jnp.float32)))
+                        for f in solves)
+            for f, tr in enumerate(train):
+                assert np.all(new[f][~tr] == 0.0)
+                ev = np.linalg.eigvalsh(
+                    np.asarray(k_full, np.float64)[np.ix_(tr, tr)])
+                ln = lams * tr.sum()
+                cond = (ev[-1] + ln) / (max(ev[0], 0.0) + ln)
+                gap = np.max(np.abs(new[f] - old[f]), axis=0)
+                tol = 16 * eps * cond * np.max(np.abs(old[f]), axis=0)
+                assert np.all(gap <= tol), (s, g, f, gap, tol)
+
+
+def _lowered(solver, k=300, slots=2):
+    cfg = cv_mod.CVConfig(solver=solver, n_folds=FOLDS, max_iters=20,
+                          keep_surface=True)
+    base = grids.liquid_grid(n=k, dim=3, median_dist=1.0, grid_choice=0,
+                             cell_size=2000)
+    lam_c, sub_c, task_c, n_lam, n_sub = cv_mod.grid_columns(base, cfg, 1)
+    ones = jnp.ones((slots, 1, k), jnp.float32)
+    args = (jnp.zeros((slots, k, 3), jnp.float32), ones, ones,
+            jnp.ones((slots, k), jnp.float32),
+            jnp.tile(jnp.asarray(base.gammas, jnp.float32)[None], (slots, 1)),
+            jax.random.split(jax.random.PRNGKey(0), slots))
+
+    def wave(*a):
+        return cell_trainer.train_cells(*a, lam_c, sub_c, task_c, cfg, n_lam,
+                                        n_sub)
+    return jax.make_jaxpr(wave)(*args), jax.jit(wave).lower(*args).as_text()
+
+
+def _lowered_with_the_masked_full_solve(solver):
+    saved = ls.solve_columns
+    ls.solve_columns = lambda k, y, lam, n_eff, t, n_c: _masked_full_solve(
+        k, y, lam, n_eff, t)
+    try:
+        jax.clear_caches()
+        return _lowered(solver)[1]
+    finally:
+        ls.solve_columns = saved
+        jax.clear_caches()
+
+
+@pytest.mark.parametrize("solver", ["ls", "hinge"])
+def test_wave_factors_only_the_fold_block(solver):
+    """The ls wave's every Cholesky is of side ``ls_factor_side(k,
+    folds)`` (k = 300, 5 folds: 256); the hinge wave lowers to the same
+    program whichever ls solve the CV holds, while the ls wave does not."""
+    jaxpr, text = _lowered(solver)
+    chol = [e.invars[0].aval.shape for e in _eqns(jaxpr.jaxpr)
+            if e.primitive.name == "cholesky"]
+    if solver == "ls":
+        side = cv_mod.ls_factor_side(300, FOLDS)
+        assert chol and all(s[-2:] == (side, side) for s in chol), chol
+        assert _lowered_with_the_masked_full_solve(solver) != text
+    else:
+        assert not chol
+        assert _lowered_with_the_masked_full_solve(solver) == text
