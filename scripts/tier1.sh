@@ -175,57 +175,4 @@ PY
 
 echo "tier1: embed cycle OK"
 
-# fused-solver parity smoke: (1) training with the wave-fused CD polish
-# (SOLVER_POLISH) must leave every argmin (gamma, lambda) decision of the
-# FISTA-only path unchanged and keep coefs inside the tol band; (2) the
-# fused wave CD launch must agree with per-slot launches within solver
-# tolerance (the cd_solver wave-fusion contract, end to end)
-PYTHONPATH=src python - <<'PY'
-import numpy as np
-import jax.numpy as jnp
-from repro.api import SVM
-from repro.data.synthetic import covtype_like, train_test_split
-from repro.kernels.cd_solver import ops as cd_ops
-from repro.train.svm_trainer import SVMTrainerConfig
-
-x, y = covtype_like(n=240, d=4, seed=5, label_noise=0.05, n_modes=3)
-xtr, ytr, _, _ = train_test_split(x, np.where(y == 0, -1, 1), 0.25, 5)
-sels = {}
-for pol in (0, 2):
-    cfg = SVMTrainerConfig(n_folds=2, max_iters=150, adaptivity_control=1,
-                           cd_polish=pol)
-    sess = SVM(xtr, ytr, config=cfg)
-    sess.train()
-    sels[pol] = sess.select("argmin")
-plain, polished = sels[0], sels[2]
-assert np.array_equal(plain.gamma, polished.gamma), \
-    "cd_polish moved an argmin gamma decision"
-assert np.array_equal(plain.lam, polished.lam), \
-    "cd_polish moved an argmin lambda decision"
-diff = float(np.max(np.abs(plain.coefs - polished.coefs)))
-assert diff <= 50 * plain.cv_cfg.tol, \
-    f"polished coefs drifted {diff} beyond the tol band"
-
-rng = np.random.default_rng(0)
-s, n, p = 3, 96, 4
-a = rng.normal(size=(s, n, n)).astype(np.float32)
-k = jnp.asarray(np.einsum("sij,skj->sik", a, a) / n
-                + np.eye(n, dtype=np.float32))
-yv = jnp.asarray(rng.normal(size=(s, n, p)), jnp.float32)
-hi = jnp.asarray(np.abs(rng.normal(size=(s, n, p))) + 0.1, jnp.float32)
-lo, c0 = -hi, jnp.zeros((s, n, p), jnp.float32)
-fused = cd_ops.cd_epochs_wave(k, yv, lo, hi, c0, epochs=3)
-for i in range(s):
-    slot = cd_ops.cd_epochs(k[i], yv[i], lo[i], hi[i], c0[i], epochs=3)
-    gap = float(jnp.max(jnp.abs(fused[i] - slot)))
-    assert gap <= 1e-3, f"wave slot {i} disagrees with per-slot launch: {gap}"
-print("tier1: fused-solver parity OK")
-PY
-
-# perf-regression gate: compare a fresh quick-mode drain against the
-# committed BENCH_serve.json baselines (wide tolerances — catches
-# collapses, not machine noise; REPRO_SKIP_REGRESSION=1 for the
-# baseline-only validation)
-PYTHONPATH=src python -m benchmarks.check_regression
-
 echo "tier1: OK"

@@ -11,7 +11,7 @@ Layers:
   repro.tasks        OvA/AvA/NP/quantile task creation
   repro.data         synthetic data + scaling + LM token pipeline
   repro.distributed  mesh-aware cell sharding, compression, planner
-  repro.kernels      Pallas TPU kernels (kernel_matrix, cd_solver, svm_predict,
+  repro.kernels      Pallas TPU kernels (kernel_matrix, svm_predict,
                      flash_attention) with jnp oracles
   repro.models       assigned LM architectures (GQA/MoE/RWKV6/Mamba/hybrid)
   repro.train        optimizers, checkpointing, fault tolerance, loops

@@ -18,10 +18,6 @@ Train-stage keys
   GRID_CHOICE          int    0|1|2 -> 10x10 | 15x15 | 20x20 grid
   ADAPTIVITY_CONTROL   int    0|1|2 coarse-grid subsetting (paper App. C)
   MAX_ITERATIONS       int    solver iteration cap
-  SOLVER_POLISH        int    Gauss-Seidel CD epochs appended to each
-                       box-QP solve (kernels/cd_solver, wave-fused over
-                       the cell batch); 0 = off, bitwise-identical to the
-                       FISTA-only path
   TOLERANCE            float  solver duality-gap tolerance
   RANDOM_SEED          int    fold/cell PRNG seed
   VORONOI              int|str cell decomposition: 0=none 1=random
@@ -168,8 +164,6 @@ _KEYS: Dict[str, ConfigKey] = {k.name: k for k in [
               field="adaptivity_control", lo=0, hi=2),
     ConfigKey("MAX_ITERATIONS", "int", "solver iteration cap",
               field="max_iters", lo=1),
-    ConfigKey("SOLVER_POLISH", "int", "wave-fused CD polish epochs (0 = off)",
-              field="cd_polish", lo=0),
     ConfigKey("TOLERANCE", "float", "solver tolerance", field="tol", lo=0.0),
     ConfigKey("RANDOM_SEED", "int", "PRNG seed", field="seed"),
     ConfigKey("VORONOI", "", "cell decomposition code/name"),

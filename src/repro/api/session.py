@@ -621,7 +621,7 @@ class SVM:
             solver=cfg.resolve_solver(), kernel=cfg.kernel,
             n_folds=cfg.n_folds, fold_scheme=cfg.fold_scheme, tol=cfg.tol,
             max_iters=cfg.max_iters, taus=cfg.taus, weights=cfg.weights,
-            keep_surface=True, cd_polish=cfg.cd_polish)
+            keep_surface=True)
 
         base_grid = grids.liquid_grid(n=k, dim=d, median_dist=1.0,
                                       grid_choice=cfg.grid_choice,

@@ -19,12 +19,12 @@ Distance-cache pipeline: both built-in kernels factor through the
 gamma-independent D², so the Gram rematerialization cost across an n_gamma
 grid drops from n_gamma GEMMs to one GEMM plus n_gamma elementwise passes
 (kernels that do not factor — see ``kernel_fns.KernelSpec`` — fall back to
-one full evaluation per gamma, as does ``cache_d2=False``, kept as the
-benchmark baseline).  On TPU the D² kernel computes only upper-triangle
-tiles and mirrors them (``sq_dists_pallas(symmetric=True)``), and the bf16
-read path for the hinge/quantile solvers is fused into the per-gamma
-epilogue's single VMEM pass (``gram_from_d2_pallas(out_dtype="bf16")``) —
-the Gram is never materialized in f32 at all on that path.
+one full evaluation per gamma).  On TPU the D² kernel computes only
+upper-triangle tiles and mirrors them (``sq_dists_pallas(symmetric=True)``),
+and the bf16 read path for the hinge/quantile solvers is fused into the
+per-gamma epilogue's single VMEM pass (``gram_from_d2_pallas`` with
+``out_dtype="bf16"``) — the Gram is never materialized in f32 at all on
+that path.
 
 Columns are task-major:  col = t * (n_lam * n_sub) + l * n_sub + s, where
 "sub" is the quantile/expectile tau or the hinge class-weight index.
@@ -52,7 +52,6 @@ import jax.numpy as jnp
 from repro.core import kernel_fns
 from repro.core.grids import GridSpec
 from repro.core.solvers import base as qp
-from repro.kernels.cd_solver import ops as cd_ops
 from repro.core.solvers import expectile as exp_solver
 from repro.core.solvers import hinge as hinge_solver
 from repro.core.solvers import least_squares as ls_solver
@@ -70,13 +69,8 @@ class CVConfig:
     tol: float = 1e-3
     max_iters: int = 1000
     val_loss: str = "auto"          # auto: 0-1 for hinge, mse for ls, pinball, ...
-    shared_lipschitz: bool = True   # one L per gamma (False: per-fold masked
-                                    # Gram + power iteration — the baseline)
     gram_dtype: str = "f32"         # f32 | bf16 (hinge/quantile solve reads
                                     # a 2-byte Gram, accumulates f32 — §Perf)
-    cache_d2: bool = True           # hoist the gamma-independent D² out of
-                                    # the gamma scan (False: recompute the
-                                    # full Gram per gamma — the baseline)
     keep_surface: bool = False      # retain the full validation surface
                                     # (loss + hinge FA/detection counts) per
                                     # grid point — the staged select() phase
@@ -84,12 +78,6 @@ class CVConfig:
                                     # retraining (repro.api.session)
     taus: Tuple[float, ...] = (0.5,)       # quantile/expectile levels (sub axis)
     weights: Tuple[float, ...] = (1.0,)    # hinge +1-class weight grid (sub axis)
-    cd_polish: int = 0              # Gauss-Seidel polish epochs after the
-                                    # batched box-QP (hinge/quantile): the
-                                    # warm-started CD pass from
-                                    # kernels/cd_solver, wave-fused under the
-                                    # cell vmap.  0 = off (bitwise-identical
-                                    # to the FISTA-only path)
 
     @property
     def n_sub(self) -> int:
@@ -194,10 +182,7 @@ def _solve_columns(k_full, y_cols, train_cols, lam_c, sub_c, n_eff_cols, cfg, c0
 
     Returns ``(c, iters)`` — iters is the box-QP iteration count (0 for the
     direct ls/expectile solves), surfaced so callers can assert that warm
-    starts actually shorten the solve.  ``cfg.cd_polish > 0`` appends that
-    many Gauss-Seidel epochs (``kernels/cd_solver``) after the box-QP —
-    warm-started from the FISTA iterate, monotone, and wave-fused when the
-    caller is vmapped over cells.
+    starts actually shorten the solve.
     """
     if cfg.solver in ("hinge", "quantile"):
         cost = 1.0 / (2.0 * lam_c[None, :] * jnp.maximum(n_eff_cols[None, :], 1.0))
@@ -208,13 +193,9 @@ def _solve_columns(k_full, y_cols, train_cols, lam_c, sub_c, n_eff_cols, cfg, c0
         else:
             lo = cost * (sub_c[None, :] - 1.0) * train_cols
             hi = cost * sub_c[None, :] * train_cols
-        y_eff = y_cols * train_cols
-        res = qp.box_qp(k_full, y_eff, lo, hi, c0=c0,
+        res = qp.box_qp(k_full, y_cols * train_cols, lo, hi, c0=c0,
                         tol=cfg.tol, max_iters=cfg.max_iters, l_est=l_est)
-        c = res.c
-        if cfg.cd_polish > 0:
-            c = cd_ops.cd_polish(k_full, y_eff, lo, hi, c, cfg.cd_polish)
-        return c, res.iters
+        return res.c, res.iters
     if cfg.solver == "ls":
         # all columns must share the fold train mask (task_mask == 1)
         c = ls_solver.solve_columns(
@@ -256,7 +237,7 @@ def cv_cell(
     colmask = task_mask[task_c].T * mask[:, None]              # (n, P)
 
     spec = kernel_fns.get_spec(cfg.kernel)
-    use_d2 = cfg.cache_d2 and spec.factors_through_d2
+    use_d2 = spec.factors_through_d2
     want_bf16 = cfg.gram_dtype == "bf16" and cfg.solver in ("hinge", "quantile")
     gram_dtype = "bf16" if want_bf16 else "f32"
     track_rates = cfg.keep_surface and cfg.solver == "hinge"
@@ -283,22 +264,15 @@ def cv_cell(
 
         # ONE Lipschitz estimate per gamma, shared by every fold: for a PSD
         # Gram, lambda_max(M K M) <= lambda_max(K) for any 0/1 mask M, so
-        # the shared step 1/L is valid for all masked subproblems.  This
-        # removes n_folds (n, n) masked-Gram materializations + per-fold
-        # power iterations (§Perf hillclimb: SVM cell trainer).
-        needs_l = cfg.solver in ("hinge", "quantile")
-        l_shared = (qp.power_iteration_l(k_full)
-                    if (needs_l and cfg.shared_lipschitz) else None)
+        # the shared step 1/L is valid for all masked subproblems, and no
+        # fold builds its own masked Gram.
+        l_est = (qp.power_iteration_l(k_full)
+                 if cfg.solver in ("hinge", "quantile") else None)
 
         def per_fold(tr_mask, va_mask, c0_f):
             tr_cols = tr_mask.astype(jnp.float32)[:, None] * colmask   # (n, P)
             va_cols = va_mask.astype(jnp.float32)[:, None] * colmask
             n_eff_cols = jnp.sum(tr_cols, axis=0)                      # (P,)
-            if needs_l and not cfg.shared_lipschitz:  # baseline path
-                mt = tr_mask.astype(jnp.float32)
-                l_est = qp.power_iteration_l(k_full * mt[:, None] * mt[None, :])
-            else:
-                l_est = l_shared
             coefs, iters = _solve_columns(k_full, y_cols, tr_cols, lam_c,
                                           sub_c, n_eff_cols, cfg, c0_f, l_est)
             f_val = jnp.matmul(k_full, coefs,
@@ -379,9 +353,8 @@ def _solve_columns_at_core(x, y_tasks, task_mask, mask, gamma, lam_cols,
     k_full = spec.fn(x, x, gamma)
     if cfg.gram_dtype == "bf16" and cfg.solver in ("hinge", "quantile"):
         k_full = k_full.astype(jnp.bfloat16)
-    needs_l = cfg.solver in ("hinge", "quantile")
-    l_shared = (qp.power_iteration_l(k_full)
-                if (needs_l and cfg.shared_lipschitz) else None)
+    l_est = (qp.power_iteration_l(k_full)
+             if cfg.solver in ("hinge", "quantile") else None)
     n, p_cols = x.shape[0], lam_cols.shape[0]
     if c0 is None:
         c0 = jnp.zeros((cfg.n_folds, n, p_cols), jnp.float32)
@@ -400,11 +373,6 @@ def _solve_columns_at_core(x, y_tasks, task_mask, mask, gamma, lam_cols,
     def per_fold(tr_mask, c0_f):
         tr_cols = tr_mask.astype(jnp.float32)[:, None] * colmask
         n_eff_cols = jnp.sum(tr_cols, axis=0)
-        if needs_l and not cfg.shared_lipschitz:
-            mt = tr_mask.astype(jnp.float32)
-            l_est = qp.power_iteration_l(k_full * mt[:, None] * mt[None, :])
-        else:
-            l_est = l_shared
         return _solve_columns(k_full, y_cols, tr_cols, lam_cols, sub_cols,
                               n_eff_cols, cfg, c0_f, l_est)
 
@@ -443,9 +411,8 @@ def solve_columns_at(
       cached grid column from ``TrainResult``.  Measured effect on the
       batched FISTA iteration count: roughly neutral — FISTA's count is
       gated by the worst-conditioned column, and a neighbor-grid start is
-      far from that column's optimum (the gamma-scan warm starts that DO
-      pay are the CD path's; see ``benchmarks/roofline.py``).  Kept
-      because clipping makes it free and never worse than cold.
+      far from that column's optimum.  Kept because clipping makes it
+      free and never worse than cold.
     * ``(folds, n, P')`` — per-fold starts.  When these are the fold
       coefs of a previous solve of the SAME columns (the third return
       value), each fold starts at its own optimum and the re-solve

@@ -1,6 +1,6 @@
 from repro.core.solvers.base import BoxQPResult, box_qp, kkt_residual, power_iteration_l
 from repro.core.solvers.hinge import hinge_boxes, solve_hinge
-from repro.core.solvers.least_squares import solve_krr_eigh, solve_krr_chol
+from repro.core.solvers.least_squares import solve_krr_chol
 from repro.core.solvers.quantile import quantile_boxes, solve_quantile
 from repro.core.solvers.expectile import solve_expectile
 
@@ -11,7 +11,6 @@ __all__ = [
     "power_iteration_l",
     "hinge_boxes",
     "solve_hinge",
-    "solve_krr_eigh",
     "solve_krr_chol",
     "quantile_boxes",
     "solve_quantile",

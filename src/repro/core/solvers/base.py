@@ -15,9 +15,7 @@ The iteration is FISTA (accelerated projected gradient) with gradient-based
 adaptive restart; the step is 1/L with L from power iteration (shared across
 all columns, K is shared).  liquidSVM's sequential 2D-working-set CD is
 latency-bound on a systolic machine; block/batched first-order iterations
-reach the same KKT point (asserted in tests) with matmul-shaped work.  A
-faithful in-VMEM Gauss-Seidel CD sweep lives in
-``repro.kernels.cd_solver`` and can be used as a polishing pass.
+reach the same KKT point (asserted in tests) with matmul-shaped work.
 
 Stopping: projected-gradient (KKT) residual, uniform across solvers:
 ``r = || c - clip(c - g, lo, hi) ||_inf`` with ``g = K c - y``.  It is
@@ -86,10 +84,9 @@ def clip_warm_start(c0: Array, lo: Array, hi: Array) -> Array:
     The grid-neighbor solution being reused generally lives in a DIFFERENT
     box (lambda and the class weight scale it; a moved select-phase winner
     may change both), so the projection is mandatory before any solver
-    touches it: both the FISTA iteration below and the Gauss-Seidel polish
-    (``repro.kernels.cd_solver``) require ``lo <= c0 <= hi`` — from a
-    feasible start their descent is monotone, so a clipped warm start can
-    never end worse than the cold ``c0 = 0`` it replaces.
+    touches it: the FISTA iteration below requires ``lo <= c0 <= hi``, and
+    from any feasible start it converges to the same box-QP optimum as
+    the cold ``c0 = 0`` it replaces.
     """
     return jnp.clip(c0, lo, hi)
 

@@ -7,17 +7,11 @@ The CV (:func:`solve_columns`) takes one Cholesky per lambda
 (:func:`solve_krr_chol`): XLA's Cholesky is batched over the CV's vmapped
 (slot, fold) lanes, compiles in seconds and costs n^3 / 3 per lambda, and
 in f32 it does not break down at liquidSVM's smallest lambda (lambda n
-~ 1e-4 against ||K|| ~ n).
-
-:func:`solve_krr_eigh` instead eigendecomposes the (masked) Gram matrix
-once and sweeps the whole lambda path as a diagonal rescale:
-
-    K = U diag(s) U^T   =>   c(lambda) = U diag(1/(s + lambda n)) U^T y
-
-O(n^3) once + O(n^2) per lambda.  The CV does not take it: JAX's TPU eigh
-(QDWH, spectral divide and conquer) maps over every batch axis, so the
-vmapped eighs would run one after another, and one eigh at n = 2853
-compiles for minutes into ~0.9 GB of device code.
+~ 1e-4 against ||K|| ~ n).  An eigendecomposition would sweep the whole
+lambda path as a diagonal rescale, but JAX's TPU eigh (QDWH, spectral
+divide and conquer) maps over every batch axis, so the vmapped eighs
+would run one after another, and one eigh at n = 2853 compiles for
+minutes into ~0.9 GB of device code.
 
 Compaction: a fold's validation rows and the cell's padding rows carry
 no part of its system, so :func:`solve_columns` gathers the fold's
@@ -35,10 +29,6 @@ to cell and stay in the block, masked, as do the rows the rounding adds.
 Masking: with M = diag(train_mask), the Cholesky of M K M + lambda n I
 solves the fold subproblem exactly — padded coordinates see
 (0 + lambda n) c = 0 => c = 0, and the factor keeps them apart exactly.
-The eigh path factors M K M + I - M, so that they split off as unit
-eigenvalues: as zero eigenvalues f32 round-off would mix them with the
-training block's smallest, and the path's largest weights (1 / lambda n)
-would leak onto rows that are not trained on.
 """
 from __future__ import annotations
 
@@ -46,7 +36,6 @@ import jax
 import jax.numpy as jnp
 
 Array = jax.Array
-HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _masked(k_mat: Array, train_mask: Array | None) -> Array:
@@ -78,30 +67,6 @@ def solve_columns(k_mat: Array, y: Array, lambdas: Array, n_eff: Array,
     with jax.named_scope("cv.ls_path"):
         return jnp.zeros((k_mat.shape[0], c.shape[0]), jnp.float32) \
             .at[rows].set(c.T, unique_indices=True)
-
-
-def solve_krr_eigh(
-    k_mat: Array,
-    y: Array,
-    lambdas: Array,       # (P,)
-    n_eff: Array,
-    train_mask: Array | None = None,
-) -> Array:
-    """All-lambda KRR path via one eigh.  Returns c (n, P), 0 outside
-    ``train_mask``.  Both matmuls run at ``HIGHEST``: on a TPU an f32
-    matmul at the default precision is one bf16 pass."""
-    km = _masked(k_mat.astype(jnp.float32), train_mask)
-    y = y.astype(jnp.float32)
-    if train_mask is not None:
-        m = train_mask.astype(jnp.float32)
-        km = km + jnp.diag(1.0 - m)
-        y = y * m
-    s, u = jnp.linalg.eigh(km)
-    s = jnp.maximum(s, 0.0)  # PSD clip against f32 round-off
-    uty = jnp.matmul(u.T, y, precision=HIGHEST)  # (n,)
-    denom = s[:, None] + lambdas[None, :].astype(jnp.float32) * jnp.maximum(n_eff, 1.0)  # (n, P)
-    c = jnp.matmul(u, uty[:, None] / denom, precision=HIGHEST)
-    return c if train_mask is None else c * m[:, None]
 
 
 def solve_krr_chol(

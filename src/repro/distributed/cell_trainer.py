@@ -14,13 +14,6 @@ each worker solves its coarse cell via fine cells of <= 2000.  Here:
     (embarrassingly parallel by construction — the paper's observed
     superlinear Spark speedup is the same effect).
 
-With ``cfg.cd_polish > 0`` each cell's box-QP iterate gets that many
-Gauss-Seidel epochs from ``repro.kernels.cd_solver`` appended; under this
-module's vmap over slots those per-cell polishes execute as ONE wave-fused
-CD pass per gamma (the ``cd_epochs_wave`` launch shape — see the wave
-fusion contract in ``kernels/cd_solver/cd_solver.py``), so the polish
-rides the wave for free instead of serializing per slot.
-
 Test phase: test points are routed host-side to their owning cell
 (nearest center — Voronoi routing), padded per slot, and evaluated with
 the same sharding.
@@ -245,7 +238,7 @@ def train_cells_waves(
             with obs.tracer.span("train.wave.stage"):
                 arrays = stage(lo, lo + wave_size)
             with obs.tracer.span("train.wave.solve") as sp:
-                sp.set(wave=w, slots=wave_size, cd_polish=cfg.cd_polish)
+                sp.set(wave=w, slots=wave_size)
                 args = [jnp.asarray(a) for a in arrays]
                 if obs.tracer.enabled:
                     jaxprof.note(train_cells, *args, lam_c, sub_c, task_c,

@@ -1,7 +1,6 @@
 # TPU Pallas kernels for liquidSVM's compute hot spots (the parts the paper
 # implements with SIMD/CUDA):
 #   kernel_matrix    — tiled Gram-matrix computation (MXU cross term)
-#   cd_solver        — in-VMEM (block) Gauss-Seidel coordinate descent sweep
 #   svm_predict      — fused K(test, SV) @ coefs evaluation, no Gram in HBM
 #   flash_attention  — causal/windowed/bidirectional flash for the LM stack
 # Each package ships <name>.py (pallas_call + BlockSpec), ops.py (jit'd

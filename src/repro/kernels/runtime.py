@@ -11,8 +11,8 @@ Passing an explicit bool still wins (tests force ``interpret=True`` to
 validate kernel bodies off-TPU).
 
 :func:`enable_compile_cache` places JAX's persistent compilation cache for
-the process entry points (``chip_smoke.py``, ``python -m repro.cli``,
-``python -m benchmarks.run``); nothing calls it at import.
+the process entry points (``chip_smoke.py``, ``python -m repro.cli``);
+nothing calls it at import.
 """
 from __future__ import annotations
 

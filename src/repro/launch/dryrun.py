@@ -254,14 +254,12 @@ def dryrun_cell(arch_id: str, shape_name: str, mesh, mesh_name: str,
 
 def dryrun_svm(mesh, mesh_name: str, slots_per_dev: int = 2, k: int = 2000,
                d: int = 128, verbose: bool = True,
-               shared_lipschitz: bool = True,
                gram_dtype: str = "f32") -> Dict[str, Any]:
     """Roofline the paper's own technique: the sharded cell-CV trainer.
 
     One slot = one padded cell of k samples; the full 10x10 grid x 5 folds
-    CV runs per slot, slots sharded over every mesh axis.
-    shared_lipschitz=False is the paper-faithful baseline (per-fold masked
-    Gram); True + gram_dtype="bf16" are the §Perf-optimized variants."""
+    CV runs per slot, slots sharded over every mesh axis, at the given
+    Gram dtype."""
     from repro.core import cv as cv_mod
     from repro.core.grids import liquid_grid
     from repro.distributed.cell_trainer import train_cells
@@ -269,9 +267,7 @@ def dryrun_svm(mesh, mesh_name: str, slots_per_dev: int = 2, k: int = 2000,
     t0 = time.time()
     n_dev = int(np.prod(list(mesh.shape.values())))
     n_slots = n_dev * slots_per_dev
-    cfg = cv_mod.CVConfig(n_folds=5, max_iters=500,
-                          shared_lipschitz=shared_lipschitz,
-                          gram_dtype=gram_dtype)
+    cfg = cv_mod.CVConfig(n_folds=5, max_iters=500, gram_dtype=gram_dtype)
     grid = liquid_grid(n=k, dim=d)
     lam_c, sub_c, task_c, n_lam, n_sub = cv_mod.grid_columns(grid, cfg, 1)
     axes = tuple(mesh.axis_names)
@@ -304,10 +300,9 @@ def dryrun_svm(mesh, mesh_name: str, slots_per_dev: int = 2, k: int = 2000,
                                mesh=mesh, axis_names=axes),
         *args, while_trips=float(cfg.max_iters))
 
-    variant = ("sharedL" if shared_lipschitz else "baseline") + \
-        ("_bf16gram" if gram_dtype == "bf16" else "")
     result = {
-        "arch": "svm-cell-trainer", "shape": f"cells_k{k}_d{d}_{variant}",
+        "arch": "svm-cell-trainer",
+        "shape": f"cells_k{k}_d{d}_{gram_dtype}gram",
         "mesh": mesh_name, "kind": "svm_train", "n_devices": n_dev,
         "flops": structural.flops / n_dev,
         "bytes_accessed": structural.bytes / n_dev,
@@ -357,11 +352,9 @@ def main(argv=None) -> int:
     results = []
     if args.svm:
         for mesh_name, mesh in meshes:
-            for shared, gdt in ((False, "f32"), (True, "f32"),
-                                (True, "bf16")):  # baseline -> optimized
+            for gdt in ("f32", "bf16"):
                 try:
-                    r = dryrun_svm(mesh, mesh_name, shared_lipschitz=shared,
-                                   gram_dtype=gdt)
+                    r = dryrun_svm(mesh, mesh_name, gram_dtype=gdt)
                     results.append(r)
                     if args.out:
                         with open(args.out, "a") as f:

@@ -32,8 +32,7 @@ deterministically.
 Hook cost: the engine calls :meth:`observe_routing` once per admitted
 batch and :meth:`observe_requests` once per collected wave — both
 vectorized over rows — and a detached monitor costs the engine one
-``is not None`` test per batch (measured against the 2% disabled-obs bar
-in ``benchmarks/serve_microbench``).
+``is not None`` test per batch.
 """
 from __future__ import annotations
 

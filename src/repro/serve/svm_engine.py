@@ -40,8 +40,7 @@ Three serving behaviours layer on top of the batched launch:
     and launches when the queued rows would fill a bucketed wave OR the
     oldest queued request's age crosses ``deadline_ms``; every launch
     records occupancy and a request-age histogram (``wave_stats``,
-    aggregated by ``stats()`` and exported by
-    ``benchmarks/serve_throughput.py`` into ``BENCH_serve.json``).
+    aggregated by ``stats()``).
 
 Slots are LPT-ordered by :func:`plan_wave`, so sharding the slot axis over a
 mesh (as ``distributed.cell_trainer`` does for training) inherits balanced

@@ -50,10 +50,6 @@ class SVMTrainerConfig:
     np_alpha: float = 0.05          # npsvm: false-alarm budget on class -1
     tol: float = 1e-3
     max_iters: int = 1000
-    cd_polish: int = 0              # Gauss-Seidel polish epochs after each
-                                    # box-QP solve (kernels/cd_solver,
-                                    # wave-fused); 0 = off (bitwise-
-                                    # identical to the FISTA-only path)
     seed: int = 0
     scale: bool = True              # train-statistics feature scaling
     n_slots_per_wave: Optional[int] = None   # None: all slots in one wave

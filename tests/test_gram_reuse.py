@@ -8,10 +8,12 @@ Covers the contract of the gamma-reuse pipeline end to end:
   * the symmetric (upper-triangle + mirror) train-Gram path is EXACTLY
     symmetric, bitwise;
   * ``cv_cell`` with the cached D² selects identical hyper-parameters and
-    matches validation losses to <= 1e-5 vs. the per-gamma-Gram baseline.
+    matches validation losses to <= 1e-5 vs. the per-gamma full Gram, the
+    path of a kernel registered without a D² epilogue.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax.numpy as jnp
@@ -165,13 +167,25 @@ class TestBF16D2Storage:
             kernel_fns.CachedGram.build(x, d2_dtype="fp8")
 
 
+@contextlib.contextmanager
+def _without_epilogue(kernel: str):
+    """``kernel``'s function registered under a second name with no D²
+    epilogue, so ``cv_cell`` evaluates it in full once per gamma."""
+    name = f"_{kernel}_full_gram"
+    kernel_fns.register_kernel(name, kernel_fns.get_kernel(kernel))
+    try:
+        yield name
+    finally:
+        kernel_fns.unregister_kernel(name)
+
+
 class TestCVEquivalence:
     @pytest.mark.parametrize("solver,kernel", [("hinge", "gauss_rbf"),
                                                ("ls", "gauss_rbf"),
                                                ("hinge", "laplacian")])
     def test_cached_selects_same_hyperparams(self, solver, kernel):
-        """cache_d2=True must select the same (gamma, lambda) and match the
-        full validation surface to <= 1e-5 vs. the per-gamma-Gram baseline."""
+        """The cached D² must select the same (gamma, lambda) and match the
+        validation loss to <= 1e-5 vs. the per-gamma full Gram."""
         rng = np.random.default_rng(8)
         n = 120
         y = np.sign(rng.normal(size=n)).astype(np.float32)
@@ -181,8 +195,9 @@ class TestCVEquivalence:
         cfg = cv_mod.CVConfig(solver=solver, kernel=kernel, n_folds=3,
                               max_iters=200)
         m_cached = train_select(x, y, grid=g, cfg=cfg, seed=3)
-        m_base = train_select(x, y, grid=g,
-                              cfg=dataclasses.replace(cfg, cache_d2=False), seed=3)
+        with _without_epilogue(kernel) as full:
+            m_base = train_select(x, y, grid=g, seed=3,
+                                  cfg=dataclasses.replace(cfg, kernel=full))
         assert float(m_cached.gamma[0, 0]) == float(m_base.gamma[0, 0])
         assert float(m_cached.lam[0, 0]) == float(m_base.lam[0, 0])
         np.testing.assert_allclose(m_cached.val_loss, m_base.val_loss, atol=1e-5)
@@ -200,6 +215,7 @@ class TestCVEquivalence:
                 jnp.ones((n,), jnp.float32), g.gammas, lam_c, sub_c, task_c,
                 jnp.zeros(2, jnp.uint32))
         sel_c = cv_mod.cv_cell(*args, cfg, n_lam=n_lam, n_sub=n_sub)
-        sel_b = cv_mod.cv_cell(*args, dataclasses.replace(cfg, cache_d2=False),
-                               n_lam=n_lam, n_sub=n_sub)
+        with _without_epilogue(cfg.kernel) as full:
+            sel_b = cv_mod.cv_cell(*args, dataclasses.replace(cfg, kernel=full),
+                                   n_lam=n_lam, n_sub=n_sub)
         np.testing.assert_allclose(sel_c.val_grid, sel_b.val_grid, atol=1e-5)

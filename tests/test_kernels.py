@@ -10,8 +10,6 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.kernels.cd_solver import ref as cd_ref
-from repro.kernels.cd_solver.ops import cd_epochs, cd_epochs_wave
 from repro.kernels.flash_attention import ref as fa_ref
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.kernel_matrix import ref as km_ref
@@ -56,128 +54,6 @@ class TestKernelMatrix:
         # as exp(-eps/gamma^2) != 1 — inherent to the paper's own GPU trick
         np.testing.assert_allclose(np.diag(k), 1.0, atol=5e-3)
         assert (k >= 0).all() and (k <= 1.0 + 1e-4).all()  # exp may underflow to 0
-
-
-# ------------------------------------------------------------------ cd_solver
-
-class TestCDSolver:
-    @pytest.mark.parametrize("n,p", [(128, 1), (128, 16), (200, 5), (64, 3)])
-    def test_epoch_bitwise_matches_ref(self, n, p):
-        rng = np.random.default_rng(2)
-        a = rng.normal(size=(n, n)).astype(np.float32)
-        k = jnp.asarray(a @ a.T / n + np.eye(n, dtype=np.float32))
-        y = jnp.asarray(rng.normal(size=(n, p)), jnp.float32)
-        lo = jnp.full((n, p), -0.7, jnp.float32)
-        hi = jnp.full((n, p), 0.7, jnp.float32)
-        c0 = jnp.zeros((n, p), jnp.float32)
-        got = cd_epochs(k, y, lo, hi, c0, epochs=3, force_pallas=True)
-        want, _ = cd_ref.solve_cd_ref(k, y, lo, hi, c0, epochs=3)
-        np.testing.assert_allclose(got, want, atol=2e-5)
-
-    def test_padding_coordinates_stay_zero(self):
-        n, p = 100, 4  # pads to 128
-        rng = np.random.default_rng(3)
-        a = rng.normal(size=(n, n)).astype(np.float32)
-        k = jnp.asarray(a @ a.T / n)
-        y = jnp.asarray(rng.normal(size=(n, p)), jnp.float32)
-        lo, hi = jnp.full((n, p), -1.0), jnp.full((n, p), 1.0)
-        c = cd_epochs(k, y, lo.astype(jnp.float32), hi.astype(jnp.float32),
-                      jnp.zeros((n, p), jnp.float32), epochs=2, force_pallas=True)
-        assert c.shape == (n, p)
-
-    def test_monotone_dual_descent(self):
-        from repro.core.solvers.base import dual_objective
-        rng = np.random.default_rng(4)
-        a = rng.normal(size=(64, 64)).astype(np.float32)
-        k = jnp.asarray(a @ a.T / 64 + 0.1 * np.eye(64, dtype=np.float32))
-        y = jnp.asarray(np.sign(rng.normal(size=(64, 2))), jnp.float32)
-        lo, hi = jnp.minimum(0.0, y), jnp.maximum(0.0, y)
-        prev = -np.inf
-        c = jnp.zeros((64, 2), jnp.float32)
-        for _ in range(4):
-            c = cd_epochs(k, y, lo, hi, c, epochs=1, force_pallas=True)
-            obj = float(np.sum(np.asarray(dual_objective(k, y, c))))
-            assert obj >= prev - 1e-5
-            prev = obj
-
-
-class TestCDWave:
-    """Fusion contract of the wave solver (cd_solver.py module docstring):
-    the Pallas wave launch reproduces the per-slot kernel bit-for-bit, and
-    the off-TPU blocked path matches the exact oracle to f32 rounding."""
-
-    @staticmethod
-    def _wave(s, n, p, seed=5):
-        rng = np.random.default_rng(seed)
-        a = rng.normal(size=(s, n, n)).astype(np.float32)
-        k = jnp.asarray(np.einsum("sij,skj->sik", a, a) / n
-                        + np.eye(n, dtype=np.float32))
-        y = jnp.asarray(rng.normal(size=(s, n, p)), jnp.float32)
-        lo = jnp.zeros((s, n, p), jnp.float32)
-        hi = jnp.asarray(np.abs(rng.normal(size=(s, n, p))) + 0.1, jnp.float32)
-        c0 = jnp.clip(jnp.asarray(rng.normal(size=(s, n, p)) * 0.05,
-                                  jnp.float32), lo, hi)
-        return k, y, lo, hi, c0
-
-    def test_wave_pallas_bitwise_per_slot(self):
-        # at an exact block multiple the fused wave launch must equal S
-        # per-slot launches BIT-FOR-BIT (same coordinate sequence)
-        s, n, p = 3, 128, 4
-        k, y, lo, hi, c0 = self._wave(s, n, p)
-        fused = cd_epochs_wave(k, y, lo, hi, c0, epochs=2, force_pallas=True)
-        for i in range(s):
-            slot = cd_epochs(k[i], y[i], lo[i], hi[i], c0[i], epochs=2,
-                             force_pallas=True)
-            np.testing.assert_array_equal(np.asarray(fused[i]),
-                                          np.asarray(slot))
-
-    def test_wave_pallas_padded_matches_per_slot(self):
-        # padded n: the g0 = K c0 matmul pads, shifting reduction order —
-        # f32-rounding parity, not bitwise
-        s, n, p = 2, 150, 3
-        k, y, lo, hi, c0 = self._wave(s, n, p, seed=6)
-        fused = cd_epochs_wave(k, y, lo, hi, c0, epochs=2, force_pallas=True)
-        assert fused.shape == (s, n, p)
-        for i in range(s):
-            slot = cd_epochs(k[i], y[i], lo[i], hi[i], c0[i], epochs=2,
-                             force_pallas=True)
-            np.testing.assert_allclose(np.asarray(fused[i]),
-                                       np.asarray(slot), atol=1e-5)
-
-    @pytest.mark.parametrize("n", [128, 96, 150])  # multiple / exact / padded
-    def test_wave_blocked_matches_oracle(self, n):
-        # the production off-TPU path (delayed trailing updates) reaches the
-        # exact sweep's iterates to f32 rounding, padding included
-        s, p = 2, 5
-        k, y, lo, hi, c0 = self._wave(s, n, p, seed=7)
-        got = cd_epochs_wave(k, y, lo, hi, c0, epochs=3)
-        want, _ = cd_ref.solve_cd_wave_ref(k, y, lo, hi, c0, 3)
-        assert got.shape == (s, n, p)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-5)
-
-    def test_blocked_padding_coordinates_inert(self):
-        # a cell whose true size is below the padded width: padded rows
-        # carry lo == hi == 0 and must solve to exactly 0
-        n_true, n_pad, p = 40, 64, 3
-        rng = np.random.default_rng(8)
-        a = rng.normal(size=(n_true, n_true)).astype(np.float32)
-        k = np.zeros((1, n_pad, n_pad), np.float32)
-        k[0, :n_true, :n_true] = a @ a.T / n_true + np.eye(n_true)
-        y = np.zeros((1, n_pad, p), np.float32)
-        y[0, :n_true] = rng.normal(size=(n_true, p))
-        box = np.zeros((1, n_pad, p), np.float32)
-        box[0, :n_true] = 0.9
-        c = cd_epochs_wave(jnp.asarray(k), jnp.asarray(y),
-                           jnp.asarray(-box), jnp.asarray(box),
-                           jnp.zeros((1, n_pad, p), jnp.float32), epochs=2)
-        assert np.all(np.asarray(c)[0, n_true:] == 0.0)
-        want, _ = cd_ref.solve_cd_ref(
-            jnp.asarray(k[0, :n_true, :n_true]), jnp.asarray(y[0, :n_true]),
-            jnp.asarray(-box[0, :n_true]), jnp.asarray(box[0, :n_true]),
-            jnp.zeros((n_true, p), jnp.float32), 2)
-        np.testing.assert_allclose(np.asarray(c)[0, :n_true],
-                                   np.asarray(want), atol=2e-5)
 
 
 # ----------------------------------------------------------------- svm_predict

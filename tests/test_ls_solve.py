@@ -4,16 +4,14 @@ What is pinned here and why:
   * every matmul of the ls solve runs at ``HIGHEST``: on a TPU an f32
     matmul at the default precision is one bf16 pass.  The CV's branch
     solves by Cholesky and triangular solves, whose products XLA computes
-    at ``HIGHEST``; ``solve_krr_eigh``'s two matmuls say so themselves;
+    at ``HIGHEST``;
   * ``train_cells_waves`` with ``solver="ls"`` over three small cells of the
     benchmark's YearPredictionMSD-shaped rows (d = 90, 5 folds, the 10x10
     grid) selects the same (gamma, lambda), and gives the same validation
     surface and fold-averaged model, as a plain float64 numpy kernel-ridge
     CV;
   * the model is exactly 0 off each fold's training rows at every lambda,
-    the smallest included, on the CV's solve and on ``solve_krr_eigh``
-    (whose masked rows must not mix with the training block's smallest
-    eigenvalues);
+    the smallest included, on the CV's solve;
   * the CV's solve factors a block of ``cv.ls_factor_side(n, folds)``
     rows, not the padded cell: no fold of ``make_fold_masks`` trains on
     more than ``cv.fold_train_bound`` rows (and some fold on exactly that
@@ -64,7 +62,7 @@ def _eqns(jaxpr):
                     yield from _eqns(sub)
 
 
-@pytest.mark.parametrize("solve", ["cv_branch", "solve_krr_eigh"])
+@pytest.mark.parametrize("solve", ["cv_branch"])
 def test_ls_branch_matmuls_run_at_highest(solve):
     n, p = 16, 4
     rng = np.random.default_rng(0)
@@ -74,25 +72,16 @@ def test_ls_branch_matmuls_run_at_highest(solve):
     train = jnp.asarray(rng.uniform(size=(n, 1)) < 0.7, jnp.float32) \
         * jnp.ones((1, p))
     lam_c = jnp.asarray([1.0, 0.1, 0.01, 0.001], jnp.float32)
-    if solve == "cv_branch":
-        cfg = cv_mod.CVConfig(solver="ls")
-        jaxpr = jax.make_jaxpr(
-            lambda k, y, t: cv_mod._solve_columns(
-                k, y, t, lam_c, jnp.ones(p), jnp.sum(t, axis=0), cfg, None,
-                None)[0])(k_full, y_cols, train)
-    else:
-        jaxpr = jax.make_jaxpr(
-            lambda k, y, t: ls.solve_krr_eigh(k, y, lam_c, jnp.sum(t),
-                                              t))(k_full, y_cols[:, 0],
-                                                  train[:, 0])
+    cfg = cv_mod.CVConfig(solver="ls")
+    jaxpr = jax.make_jaxpr(
+        lambda k, y, t: cv_mod._solve_columns(
+            k, y, t, lam_c, jnp.ones(p), jnp.sum(t, axis=0), cfg, None,
+            None)[0])(k_full, y_cols, train)
     eqns = list(_eqns(jaxpr.jaxpr))
     names = {e.primitive.name for e in eqns}
     dots = [e for e in eqns if e.primitive.name == "dot_general"]
-    if solve == "cv_branch":
-        assert {"cholesky", "triangular_solve"} <= names
-        assert "eigh" not in names
-    else:
-        assert len(dots) == 2               # U^T y and U (U^T y / denom)
+    assert {"cholesky", "triangular_solve"} <= names
+    assert "eigh" not in names
     hi = jax.lax.Precision.HIGHEST
     assert all(e.params["precision"] == (hi, hi) for e in dots), [
         e.params["precision"] for e in dots]
@@ -184,7 +173,7 @@ def test_train_cells_waves_ls_matches_float64_krr_cv():
             <= 2e-4 * np.max(np.abs(c_ref))
 
 
-@pytest.mark.parametrize("solve", ["solve_columns", "solve_krr_eigh"])
+@pytest.mark.parametrize("solve", ["solve_columns"])
 def test_ls_model_is_zero_off_the_training_rows(solve):
     x, y, m, gam, keys = _cells(seed=1)
     k_full = kernel_fns.get_spec("gauss_rbf").fn(
@@ -193,14 +182,9 @@ def test_ls_model_is_zero_off_the_training_rows(solve):
     lams = jnp.asarray(grids.liquid_grid(n=K, dim=D, median_dist=1.0,
                                          grid_choice=0).lambdas)
     t = jnp.asarray(train, jnp.float32)
-    if solve == "solve_columns":
-        c = ls.solve_columns(
-            k_full, jnp.asarray(np.repeat(y[0, 0][:, None], len(lams), 1)),
-            lams, jnp.float32(train.sum()), t, cv_mod.ls_factor_side(K, 5))
-    else:
-        c = ls.solve_krr_eigh(k_full, jnp.asarray(y[0, 0]), lams,
-                              jnp.float32(train.sum()), t)
-    c = np.asarray(c)
+    c = np.asarray(ls.solve_columns(
+        k_full, jnp.asarray(np.repeat(y[0, 0][:, None], len(lams), 1)),
+        lams, jnp.float32(train.sum()), t, cv_mod.ls_factor_side(K, 5)))
     assert np.all(c[~train] == 0.0)
     assert np.all(np.isfinite(c))
 

@@ -21,6 +21,30 @@ def _gram(x, gamma=1.0):
                                jnp.float32(gamma))
 
 
+def _gauss_seidel_box_qp(k_mat, y, lo, hi, c0, epochs):
+    """Reference for the box QP: exact Gauss-Seidel coordinate descent,
+    coordinates 0..n-1 in order, every column at once.  Per coordinate i
+
+        delta = clip(c_i - g_i / K_ii, lo_i, hi_i) - c_i
+        c_i  += delta
+        g    += K[:, i] (x) delta      (g = K c - y kept up to date)
+
+    the classic liquidSVM/libsvm one-coordinate working-set step."""
+    diag = jnp.diag(k_mat)
+
+    def coord(i, state):
+        c, g = state
+        ci = c[i]
+        delta = jnp.clip(ci - g[i] / jnp.maximum(diag[i], 1e-12),
+                         lo[i], hi[i]) - ci
+        return c.at[i].add(delta), g + k_mat[:, i][:, None] * delta[None, :]
+
+    def epoch(_, state):
+        return jax.lax.fori_loop(0, k_mat.shape[0], coord, state)
+
+    return jax.lax.fori_loop(0, epochs, epoch, (c0, k_mat @ c0 - y))[0]
+
+
 # ---------------------------------------------------------------- box QP core
 
 class TestBoxQP:
@@ -45,7 +69,6 @@ class TestBoxQP:
 
     def test_matches_cd_reference_fixed_point(self):
         """FISTA and Gauss-Seidel CD land on the same box-QP optimum."""
-        from repro.kernels.cd_solver import ref as cd_ref
         rng = np.random.default_rng(3)
         x = rng.normal(size=(64, 4)).astype(np.float32)
         k = _gram(x) + 1e-3 * jnp.eye(64)
@@ -53,7 +76,7 @@ class TestBoxQP:
         lo = jnp.full((64, 3), -1.0, jnp.float32)
         hi = jnp.full((64, 3), 1.0, jnp.float32)
         c_fista = base.box_qp(k, y, lo, hi, tol=1e-7, max_iters=20000).c
-        c_cd, _ = cd_ref.solve_cd_ref(k, y, lo, hi, jnp.zeros((64, 3)), epochs=600)
+        c_cd = _gauss_seidel_box_qp(k, y, lo, hi, jnp.zeros((64, 3)), epochs=600)
         np.testing.assert_allclose(c_fista, c_cd, atol=5e-4)
 
     def test_dual_objective_monotone_in_iterations(self):
@@ -395,12 +418,15 @@ class TestQuantile:
 
 class TestLeastSquares:
     def test_eigh_path_matches_cholesky(self):
+        """The CV's lambda path (``solve_columns``, every row training)
+        gives each column what ``solve_krr_chol`` gives at its lambda."""
         rng = np.random.default_rng(10)
         x = rng.normal(size=(80, 4)).astype(np.float32)
         y = jnp.asarray(rng.normal(size=80), jnp.float32)
         k = _gram(x)
         lams = jnp.asarray([1e-3, 1e-2, 1e-1], jnp.float32)
-        c_path = ls.solve_krr_eigh(k, y, lams, jnp.float32(80))
+        c_path = ls.solve_columns(k, jnp.tile(y[:, None], (1, 3)), lams,
+                                  jnp.float32(80), jnp.ones(80), 80)
         for j, lam in enumerate(np.asarray(lams)):
             c_chol = ls.solve_krr_chol(k, y, jnp.float32(lam), jnp.float32(80))
             np.testing.assert_allclose(c_path[:, j], c_chol, atol=2e-3)
@@ -410,23 +436,28 @@ class TestLeastSquares:
         x = rng.normal(size=(50, 2)).astype(np.float32)
         y = rng.normal(size=50).astype(np.float32)
         k = _gram(x, gamma=1.5) + 1e-4 * jnp.eye(50)
-        c = ls.solve_krr_eigh(k, jnp.asarray(y), jnp.asarray([1e-9], jnp.float32),
+        c = ls.solve_krr_chol(k, jnp.asarray(y), jnp.float32(1e-9),
                               jnp.float32(50))
-        f = np.asarray(k @ c)[:, 0]
-        assert np.max(np.abs(f - y)) < 0.15  # f32 eigh conditioning floor
+        f = np.asarray(k @ c)
+        assert np.max(np.abs(f - y)) < 0.15  # f32 conditioning floor
 
     def test_masked_fold_equals_subproblem(self):
+        """``solve_columns`` gathers a fold's training rows (scattered over
+        the cell) into its block: the result is the subproblem on those
+        rows alone, and exactly 0 on every other row."""
         rng = np.random.default_rng(12)
         x = rng.normal(size=(60, 3)).astype(np.float32)
         y = rng.normal(size=60).astype(np.float32)
-        mask = np.ones(60, np.float32); mask[45:] = 0.0
+        mask = np.ones(60, np.float32); mask[rng.permutation(60)[:15]] = 0.0
+        tr = mask > 0
         k = _gram(x)
-        c_m = ls.solve_krr_eigh(k, jnp.asarray(y), jnp.asarray([1e-2], jnp.float32),
-                                jnp.float32(45), train_mask=jnp.asarray(mask))
-        c_s = ls.solve_krr_eigh(_gram(x[:45]), jnp.asarray(y[:45]),
-                                jnp.asarray([1e-2], jnp.float32), jnp.float32(45))
-        np.testing.assert_allclose(c_m[:45], c_s, atol=1e-3)
-        np.testing.assert_allclose(c_m[45:], 0.0, atol=1e-5)
+        c_m = ls.solve_columns(k, jnp.asarray(y)[:, None],
+                               jnp.asarray([1e-2], jnp.float32),
+                               jnp.float32(45), jnp.asarray(mask), 48)
+        c_s = ls.solve_krr_chol(_gram(x[tr]), jnp.asarray(y[tr]),
+                                jnp.float32(1e-2), jnp.float32(45))
+        np.testing.assert_allclose(np.asarray(c_m)[tr, 0], c_s, atol=1e-3)
+        assert np.all(np.asarray(c_m)[~tr] == 0.0)
 
 
 # ---------------------------------------------------------------- expectile
@@ -441,9 +472,9 @@ class TestExpectile:
         c_exp = exp_solver.solve_expectile(
             k, jnp.asarray(y), jnp.asarray([0.5], jnp.float32),
             jnp.asarray([1e-2], jnp.float32), jnp.float32(70))
-        c_krr = ls.solve_krr_eigh(k, jnp.asarray(y),
-                                  jnp.asarray([2e-2], jnp.float32), jnp.float32(70))
-        np.testing.assert_allclose(c_exp[:, 0], c_krr[:, 0], atol=2e-3)
+        c_krr = ls.solve_krr_chol(k, jnp.asarray(y), jnp.float32(2e-2),
+                                  jnp.float32(70))
+        np.testing.assert_allclose(c_exp[:, 0], c_krr, atol=2e-3)
 
     def test_expectile_ordering(self):
         """Higher tau => pointwise higher expectile estimate (on average)."""

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.cd_solver.cd_solver import (cd_epoch_pallas,
-                                               cd_wave_epoch_pallas)
 from repro.kernels.kernel_matrix.kernel_matrix import (gram_from_d2_pallas,
                                                        sq_dists_pallas)
 from repro.kernels.svm_predict.svm_predict import svm_predict_cells_pallas
@@ -111,12 +109,3 @@ def test_assign(one_chip, d):
     dp = D_PAD[d]
     _compile(functools.partial(_assign_pallas_padded, interpret=False),
              one_chip, ((4096, dp), F32), ((384, dp), F32))
-
-
-@pytest.mark.parametrize("wave", [False, True], ids=["cell", "wave"])
-def test_cd_epoch(one_chip, wave):
-    p = 10                               # one task x the 10-lambda grid
-    lead = (SLOTS,) if wave else ()
-    fn = cd_wave_epoch_pallas if wave else cd_epoch_pallas
-    _compile(functools.partial(fn, interpret=False), one_chip,
-             (lead + (N, N), F32), *[(lead + (N, p), F32)] * 4)
