@@ -127,33 +127,12 @@ class EmbedServe:
                    max_queue: Optional[int] = None
                    ) -> Dict[int, np.ndarray]:
         """Latency-bounded serving over a token-batch arrival stream —
-        the token-space mirror of :meth:`SVMEngine.run` (same launch
-        policy, same overlap of admission with device work, same shedding
-        semantics; ``None``/empty batches are idle ticks)."""
-        from repro.serve.svm_engine import OverloadError
-        eng = self.engine
-        results: Dict[int, np.ndarray] = {}
-        prev_mq = eng.max_queue
-        if max_queue is not None:
-            eng.max_queue = int(max_queue)
-        try:
-            for batch in traffic:
-                if batch is not None and np.size(batch):
-                    try:
-                        self.submit_tokens(batch)
-                    except OverloadError:
-                        pass         # shed; visible in engine shed_* stats
-                if eng.should_launch(deadline_ms):
-                    if eng._inflight is not None:
-                        results.update(eng.finish_step())
-                    eng.begin_step()
-            if eng._inflight is not None:
-                results.update(eng.finish_step())
-            while eng.pending:
-                results.update(eng.step())
-        finally:
-            eng.max_queue = prev_mq
-        return results
+        :meth:`SVMEngine.run`'s loop with :meth:`submit_tokens` as the
+        admission call (same launch policy, same collection of each wave
+        as soon as the device has finished it, same shedding semantics;
+        ``None``/empty batches are idle ticks)."""
+        return self.engine._run_loop(traffic, self.submit_tokens,
+                                     deadline_ms, max_queue)
 
     def predict_tokens(self, tokens) -> np.ndarray:
         """Synchronous convenience: embed + engine.predict."""

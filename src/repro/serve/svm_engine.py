@@ -40,7 +40,10 @@ Three serving behaviours layer on top of the batched launch:
     and launches when the queued rows would fill a bucketed wave OR the
     oldest queued request's age crosses ``deadline_ms``; every launch
     records occupancy and a request-age histogram (``wave_stats``,
-    aggregated by ``stats()``).
+    aggregated by ``stats()``).  The in-flight wave is collected as soon
+    as the device has finished it (:meth:`collect_ready`, polled on every
+    turn of the loop), not when the next launch comes due; a wave still
+    running at a launch is collected there, blocking.
 
 Slots are LPT-ordered by :func:`plan_wave`, so sharding the slot axis over a
 mesh (as ``distributed.cell_trainer`` does for training) inherits balanced
@@ -101,6 +104,12 @@ class OverloadError(RuntimeError):
 
     code = "ENGINE_OVERLOADED"
     retryable = True
+
+
+def _is_ready(dec) -> bool:
+    """True once the device has finished computing ``dec``; a result that
+    is not a ``jax.Array`` (already on the host) always is."""
+    return not isinstance(dec, jax.Array) or dec.is_ready()
 
 
 def blend_weights(d1: np.ndarray, d2: np.ndarray
@@ -563,10 +572,11 @@ class SVMEngine:
                                 dispatch_ms=(t_disp - t_pack) * 1e3)
         # full snapshot: a swap_bank between begin and finish must not
         # change what this wave returns or which version it is tagged with
-        # (rec rides along so finish_step can attach device/collect times)
+        # (rec and the dispatch end ride along so finish_step can attach
+        # held/device/collect times)
         self._inflight = (plan, slot_entries, dec,
                           self.bank.n_tasks, self.bank.n_sub,
-                          int(self.bank.version), rec)
+                          int(self.bank.version), rec, t_disp)
         self._tracer.record("serve.pack", t_begin, t_pack)
         self._tracer.record("serve.dispatch", t_pack, t_disp)
         self._m_waves.inc()
@@ -590,9 +600,11 @@ class SVMEngine:
         """
         if self._inflight is None:
             return {}
-        plan, slot_entries, dec, t, s_count, version, rec = self._inflight
+        (plan, slot_entries, dec, t, s_count, version, rec,
+         t_disp) = self._inflight
         self._inflight = None
         t_wait = float(self._clock())
+        rec["held_ms"] = (t_wait - t_disp) * 1e3
         with self._tracer.annotate("serve.device"):
             dec = np.asarray(dec)
             t_dev = float(self._clock())
@@ -657,6 +669,18 @@ class SVMEngine:
         self.counters["launched_rows"] += plan.n_slots * plan.m_pad
         return results
 
+    def collect_ready(self) -> Dict[int, np.ndarray]:
+        """Collect the in-flight wave if the device has finished it.
+
+        Never blocks: returns ``{}`` while the wave is still running (or
+        when none is in flight), else ``finish_step()``'s results.  Waves
+        collected here are counted in ``counters["ready_collects"]``.
+        """
+        if self._inflight is None or not _is_ready(self._inflight[2]):
+            return {}
+        self.counters["ready_collects"] += 1
+        return self.finish_step()
+
     def step(self) -> Dict[int, np.ndarray]:
         """Synchronous drain: dispatch (unless a wave is already in flight)
         and collect.  Bitwise-identical to the pre-async engine."""
@@ -668,7 +692,9 @@ class SVMEngine:
                      pack_ms: float, dispatch_ms: float) -> dict:
         """Append one wave record to the ring AND fold it into the running
         aggregates (``stats()`` reads the sums, so it stays exact after the
-        ring wraps).  ``device_ms``/``collect_ms`` are filled in by
+        ring wraps).  ``held_ms`` (dispatch end to the start of the
+        collect: how long the wave sat in flight before the host asked for
+        it), ``device_ms`` and ``collect_ms`` are filled in by
         ``finish_step`` mutating the returned dict."""
         a = np.asarray(ages, np.float64)
         hist = np.bincount(np.searchsorted(AGE_BUCKETS_MS, a, side="right"),
@@ -684,6 +710,7 @@ class SVMEngine:
             "age_hist": hist.tolist(),
             "pack_ms": pack_ms,
             "dispatch_ms": dispatch_ms,
+            "held_ms": 0.0,
             "device_ms": 0.0,
             "collect_ms": 0.0,
         }
@@ -741,11 +768,14 @@ class SVMEngine:
         ``traffic`` yields request batches ((m, d) raw-feature arrays);
         yield ``None`` or an empty batch as an idle tick so the deadline
         can force a partially-filled launch.  Launches follow
-        :meth:`should_launch`; each one is dispatched right after the
-        PREVIOUS wave is collected, so admission and host routing/packing
-        overlap device work.  Exhausting ``traffic`` drains everything.
-        Returns ``{request_id: blended (n_tasks, n_sub) decision block}``
-        for every ADMITTED request.
+        :meth:`should_launch`, so admission and host routing/packing
+        overlap device work.  Every turn of the loop polls
+        :meth:`collect_ready`: a wave is collected as soon as the device
+        has finished it, while the next one is still being held for its
+        fill or deadline; one still running at the next launch is
+        collected there by the blocking ``finish_step()``.  Exhausting
+        ``traffic`` drains everything.  Returns ``{request_id: blended
+        (n_tasks, n_sub) decision block}`` for every ADMITTED request.
 
         ``max_queue`` (or the engine-level default) bounds the admission
         queue for the duration of the run: an arrival batch that would
@@ -753,6 +783,14 @@ class SVMEngine:
         admission, counted in ``shed_*``, never assigned an id — and the
         run continues.  Graceful degradation instead of unbounded memory.
         """
+        return self._run_loop(traffic, self.submit, deadline_ms, max_queue)
+
+    def _run_loop(self, traffic: Iterable, submit: Callable,
+                  deadline_ms: Optional[float],
+                  max_queue: Optional[int]) -> Dict[int, np.ndarray]:
+        """The serve loop behind :meth:`run`, with the admission call
+        (``submit``) supplied by the caller so every front end shares one
+        launch and collect policy."""
         results: Dict[int, np.ndarray] = {}
         prev_mq = self.max_queue
         if max_queue is not None:
@@ -761,9 +799,10 @@ class SVMEngine:
             for batch in traffic:
                 if batch is not None and np.size(batch):
                     try:
-                        self.submit(batch)
+                        submit(batch)
                     except OverloadError:
                         pass             # shed; visible in shed_* counters
+                results.update(self.collect_ready())
                 if self.should_launch(deadline_ms):
                     if self._inflight is not None:
                         results.update(self.finish_step())

@@ -351,6 +351,25 @@ class TestEmbedServe:
         # exists (scores keyed by cell)
         assert mon.health()["drift"] is not None
 
+    def test_run_tokens_collects_ready_waves_bitwise_like_sync(
+            self, served, tokens, monkeypatch):
+        from repro.serve import svm_engine
+        batches = [tokens[i:i + 8] for i in range(60, 92, 8)]
+        want = []
+        for b in batches:
+            ids = served.submit_tokens(b)
+            out = served.step()
+            want.extend(out[int(i)] for i in ids)
+        monkeypatch.setattr(svm_engine, "_is_ready", lambda dec: True)
+        base = served.counters["submitted"]
+        polled = served.counters["ready_collects"]
+        results = served.run_tokens(iter(batches), deadline_ms=0.0)
+        # one wave per batch; every wave but the drained last one polled
+        assert sorted(results) == list(range(base, base + 32))
+        assert served.counters["ready_collects"] - polled == 3
+        for j, dec in enumerate(want):
+            np.testing.assert_array_equal(results[base + j], dec)
+
     def test_predict_tokens_matches_engine_on_embeddings(self, served,
                                                          extractor, tokens):
         want = served.engine.predict(extractor(tokens[:5]))

@@ -22,7 +22,11 @@ properties rather than a handful of fixed examples:
     router) at exactly-equidistant rows, duplicated centers included;
   * **deadline stepper** — with an injected clock, ``run`` launches a
     partially-filled wave exactly when the oldest queued request crosses
-    ``deadline_ms``, and fills trigger without a deadline.
+    ``deadline_ms``, and fills trigger without a deadline;
+  * **ready collection** — ``run`` collects a wave as soon as its result
+    is ready (``collect_ready``), not at the next launch; a wave that is
+    not ready is left in flight until the launch collects it, and either
+    way the results equal the synchronous drain bitwise.
 
 Strategies draw a seed (plus small structural knobs) and derive the
 request interleavings, batch sizes and deadlines from ``np.random``
@@ -39,6 +43,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 from repro.pipeline.assign import _top2_chunk, nearest_top2, nearest_top2_dists
 from repro.serve.model_bank import ModelBank
+from repro.serve import svm_engine
 from repro.serve.svm_engine import SVMEngine, blend_weights
 
 QUICK_EXAMPLES = 8
@@ -452,12 +457,13 @@ class TestDeadlineStepper:
         (w,) = eng.wave_stats
         assert set(w) == {"wave", "n_rows", "n_slots", "m_pad", "occupancy",
                           "oldest_ms", "age_ms_mean", "age_hist",
-                          "pack_ms", "dispatch_ms", "device_ms",
-                          "collect_ms"}
+                          "pack_ms", "dispatch_ms", "held_ms",
+                          "device_ms", "collect_ms"}
         assert w["n_rows"] == sum(w["age_hist"])
         assert 0.0 < w["occupancy"] <= 1.0
         assert w["wave"] == 0
-        for stage in ("pack_ms", "dispatch_ms", "device_ms", "collect_ms"):
+        for stage in ("pack_ms", "dispatch_ms", "held_ms", "device_ms",
+                      "collect_ms"):
             assert w[stage] >= 0.0
 
     def test_every_served_response_has_a_breakdown(self):
@@ -476,3 +482,114 @@ class TestDeadlineStepper:
                      + b["device_ms"] + b["collect_ms"])
             assert parts == pytest.approx(b["total_ms"], abs=1e-6)
         assert eng.breakdown(10 ** 9) is None
+
+
+class TestReadyCollect:
+    """``run`` collects the in-flight wave on the first loop turn after its
+    result is ready, instead of holding it until the next launch."""
+
+    @staticmethod
+    def _deadline_run(eng, pool, clk, seen):
+        """Wave 0 launches at 6 ms, idle ticks at 7-9 ms, wave 1 launches
+        at 14 ms; ``seen`` records whether wave 0 was answered before the
+        second launch."""
+        def traffic():
+            yield pool[:3]
+            clk[0] = 0.006
+            yield None                         # deadline: wave 0 dispatched
+            for t in (0.007, 0.008, 0.009):
+                clk[0] = t
+                yield pool[3:5] if t == 0.008 else None
+            seen["before_launch"] = eng.breakdown(0) is not None
+            seen["waves_before_launch"] = eng.stats()["waves"]
+            clk[0] = 0.014
+            yield None                         # deadline: wave 1 dispatched
+            clk[0] = 0.015
+            yield None
+        return eng.run(traffic())
+
+    def test_ready_wave_collected_before_next_launch(self, monkeypatch):
+        monkeypatch.setattr(svm_engine, "_is_ready", lambda dec: True)
+        bank, pool = _bank(13)
+        clk = [0.0]
+        eng = SVMEngine(bank, fused=False, deadline_ms=5.0,
+                        clock=lambda: clk[0])
+        seen = {}
+        results = self._deadline_run(eng, pool, clk, seen)
+        assert sorted(results) == list(range(5))
+        assert seen == {"before_launch": True, "waves_before_launch": 1}
+        # wave 0 answered on the first turn after dispatch (7 ms), not at
+        # wave 1's launch (14 ms): no next-deadline wait in its total
+        for rid in range(3):
+            assert eng.breakdown(rid)["total_ms"] == pytest.approx(7.0)
+        w0, w1 = list(eng.wave_stats)
+        assert w0["held_ms"] == pytest.approx(1.0)
+        assert w1["held_ms"] == pytest.approx(1.0)
+        assert eng.counters["ready_collects"] == 2
+
+    def test_unready_wave_waits_for_the_next_launch(self, monkeypatch):
+        monkeypatch.setattr(svm_engine, "_is_ready", lambda dec: False)
+        bank, pool = _bank(13)
+        clk = [0.0]
+        eng = SVMEngine(bank, fused=False, deadline_ms=5.0,
+                        clock=lambda: clk[0])
+        seen = {}
+        results = self._deadline_run(eng, pool, clk, seen)
+        assert sorted(results) == list(range(5))
+        assert seen == {"before_launch": False, "waves_before_launch": 1}
+        # the blocking finish_step at wave 1's launch collected wave 0
+        for rid in range(3):
+            assert eng.breakdown(rid)["total_ms"] == pytest.approx(14.0)
+        w0, w1 = list(eng.wave_stats)
+        assert w0["held_ms"] == pytest.approx(8.0)
+        assert w1["held_ms"] == pytest.approx(1.0)     # the drain
+        assert eng.counters["ready_collects"] == 0
+
+    @settings(max_examples=QUICK_EXAMPLES, deadline=None)
+    @given(seed=st.integers(0, 2 ** 20), overlap=st.booleans(),
+           ready=st.sampled_from(["always", "never", "device"]))
+    def test_run_bitwise_equals_sync_drain(self, seed, overlap, ready):
+        """One wave per batch (deadline 0): whichever way each wave is
+        collected, every id comes back once with the sync drain's bits."""
+        bank, pool = _bank(seed % 3)
+        batches = _batches(np.random.default_rng(seed), pool, 6)
+        want = _sync_drain(SVMEngine(bank, fused=False, overlap=overlap),
+                           batches)
+        eng = SVMEngine(bank, fused=False, overlap=overlap, deadline_ms=0.0)
+        hook = {"always": lambda dec: True, "never": lambda dec: False,
+                "device": svm_engine._is_ready}[ready]
+        orig = svm_engine._is_ready
+        svm_engine._is_ready = hook
+        try:
+            got = eng.run(iter(batches))
+        finally:
+            svm_engine._is_ready = orig
+        _assert_same_results(got, want)
+        assert eng.stats()["waves"] == len(batches)
+        assert eng.counters["served"] == eng.counters["submitted"]
+        if ready == "never":
+            assert eng.counters["ready_collects"] == 0
+
+    def test_ready_collects_counts_only_polled_waves(self, monkeypatch):
+        monkeypatch.setattr(svm_engine, "_is_ready", lambda dec: True)
+        bank, pool = _bank(13)
+        eng = SVMEngine(bank, fused=False, deadline_ms=0.0)
+        # wave 0 launches on batch 0 and is polled in on batch 1's turn,
+        # which launches wave 1; the drain collects wave 1
+        results = eng.run(iter([pool[:4], pool[4:8]]))
+        assert len(results) == 8
+        assert eng.stats()["waves"] == 2
+        assert eng.counters["ready_collects"] == 1
+        # direct calls: nothing in flight, then a finished wave
+        assert eng.collect_ready() == {}
+        ids = eng.submit(pool[:2])
+        eng.begin_step()
+        assert sorted(eng.collect_ready()) == [int(i) for i in ids]
+        assert eng.counters["ready_collects"] == 2
+        assert not eng.in_flight
+
+    def test_host_results_count_as_ready(self):
+        assert svm_engine._is_ready(np.zeros(3, np.float32))
+        dec = jnp.ones(3)
+        dec.block_until_ready()
+        assert svm_engine._is_ready(dec)
